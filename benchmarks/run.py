@@ -121,33 +121,46 @@ def _dump_json(path_base: str, name: str, report: dict) -> None:
 _TRACER = None
 
 
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def _roofline(flops: float, write_bytes: float, coll_bytes: float) -> dict:
+    """Roofline seconds and bottleneck against the LIVE chip's peaks
+    (``analysis.roofline.device_peaks``; an unknown chip raises).  Off a
+    TPU there is no device to bound, so the row keeps only its counts."""
+    if not _on_tpu():
+        return {}
+    from repro.analysis.roofline import device_peaks
+    pk = device_peaks()
+    terms = {"compute": flops / pk.flops_bf16,
+             "memory": write_bytes / pk.hbm_bw,
+             "collective": coll_bytes / pk.ici_bw}
+    return {"compute_s": terms["compute"], "memory_s": terms["memory"],
+            "collective_s": terms["collective"],
+            "bottleneck": max(terms, key=terms.get)}
+
+
 def _exec_cost(tag: str, jitted, *args) -> dict:
     """Per-executable cost row: FLOPs, HBM write bytes, collective bytes
-    and the roofline bottleneck of ONE jitted callable, derived AOT from
-    its compiled HLO (``lower -> compile -> as_text``; never executed).
+    (and, on a TPU, the roofline bottleneck) of ONE jitted callable,
+    derived AOT from its compiled HLO (``lower -> compile -> as_text``;
+    never executed).
 
-    Degrades to an ``{"executable", "error"}`` row instead of failing the
-    bench — cost accounting must never take down an artifact.  When a
-    family tracer is live, the compile is recorded as a ``jit.compile``
-    span annotated with the cost row.
+    Off the chip a failure degrades to an ``{"executable", "error"}`` row
+    instead of failing the bench; on a TPU it raises, so a chip run never
+    hides a broken executable behind an error row.  When a family tracer
+    is live, the compile is recorded as a ``jit.compile`` span annotated
+    with the cost row.
     """
     try:
         from repro.analysis import cost_from_hlo
-        from repro.analysis.roofline import (HBM_BW, ICI_BW,
-                                             PEAK_FLOPS_BF16)
         t0 = _TRACER.now_us() if _TRACER is not None else 0.0
         compiled = jitted.lower(*args).compile()
         cost = cost_from_hlo(compiled.as_text())
-        ca = {}
-        try:
-            ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-        except Exception:
-            ca = {}
-        terms = {"compute": cost.flops / PEAK_FLOPS_BF16,
-                 "memory": cost.write_bytes / HBM_BW,
-                 "collective": cost.collective_bytes / ICI_BW}
+        ca = compiled.cost_analysis() or {}
+        if isinstance(ca, (list, tuple)):
+            ca = ca[0] if ca else {}
         row = {
             "executable": tag, "method": "hlo",
             "flops": cost.flops,
@@ -156,19 +169,19 @@ def _exec_cost(tag: str, jitted, *args) -> dict:
             "collective_detail": cost.collective_detail,
             "xla_flops": float(ca.get("flops", 0.0)),
             "xla_bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-            "compute_s": terms["compute"], "memory_s": terms["memory"],
-            "collective_s": terms["collective"],
-            "bottleneck": max(terms, key=terms.get),
+            **_roofline(cost.flops, cost.write_bytes,
+                        cost.collective_bytes),
         }
         if _TRACER is not None:
             _TRACER.complete(
                 f"jit.compile.{tag}", t0, _TRACER.now_us() - t0,
                 lane="compile",
                 args={k: row[k] for k in ("flops", "write_bytes",
-                                          "collective_bytes",
-                                          "bottleneck")})
+                                          "collective_bytes")})
         return row
-    except Exception as e:  # pragma: no cover - platform-dependent paths
+    except Exception as e:
+        if _on_tpu():
+            raise
         return {"executable": tag, "method": "hlo", "error": str(e)}
 
 
@@ -468,7 +481,7 @@ def bench_topology_sweep(seed: int = 0) -> list[str]:
     Curves are downsampled to <= 48 points (shared indices per entry) and
     the JSON is written compact; world specs stay intact.
     """
-    from repro.analysis.roofline import HBM_BW, ICI_BW
+    from repro.analysis.roofline import V5E, device_peaks
     from repro.core import (ChurnProcess, LinkModel, PhaseSwitch, Simulator,
                             WorkerModel, World, build_graph,
                             params_from_graph)
@@ -480,10 +493,13 @@ def bench_topology_sweep(seed: int = 0) -> list[str]:
     grad_fn = _quad_grad_fn(b, noise=_TOPO_BENCH["noise"])
     # one p2p message = the d-float replica; a gradient tick reads + writes
     # the replica through HBM (the memory term of the roofline)
+    # the modelled deployment is a v5e ring, whatever host replays it
+    v5e = device_peaks(V5E)
+    ici_bw = v5e.ici_bw
     msg_bytes = float(d * 4)
-    grad_seconds = 2 * msg_bytes / HBM_BW
+    grad_seconds = 2 * msg_bytes / v5e.hbm_bw
 
-    def link_model(bandwidth=ICI_BW):
+    def link_model(bandwidth=ici_bw):
         return LinkModel(bandwidth_bytes_per_s=bandwidth,
                          msg_bytes=msg_bytes, grad_seconds=grad_seconds)
 
@@ -523,7 +539,7 @@ def bench_topology_sweep(seed: int = 0) -> list[str]:
         topology=ring, links=link_model(),
         faults=(ChurnProcess(fail_rate=0.02, repair_rate=0.2),),
         comms_per_grad=rate)
-    bw = np.full(ring.num_edges, ICI_BW)
+    bw = np.full(ring.num_edges, ici_bw)
     bw[::8] /= 8.0
     scen_worlds["ring_degraded_links"] = World(
         topology=ring,
@@ -618,7 +634,7 @@ def bench_topology_sweep(seed: int = 0) -> list[str]:
             entry["mean_alive_fraction"] = float(sched.alive_arr().mean())
             entry["num_segments"] = len(w.segments(rounds, seed))
         elif sname == "ring_degraded_links":
-            entry["slow_links"] = int((bw < ICI_BW).sum())
+            entry["slow_links"] = int((bw < ici_bw).sum())
         report["scenarios"][sname] = entry
 
     cost_fn, cost_args = sim.worlds_executable(states, scheds, params=plist)
@@ -1526,8 +1542,6 @@ def bench_train(seed: int = 0) -> list[str]:
         # second time would double a minutes-long compile for one number.
         # 6ND train FLOPs over the grid, parameter-row read+write traffic
         # per round, gossip bytes from the compiled schedules' event count
-        from repro.analysis.roofline import (HBM_BW, ICI_BW,
-                                             PEAK_FLOPS_BF16)
         from repro.analysis import model_flops
         conf = _TRAIN_BENCH["families"][fam]
         tokens = (rounds * n * conf.get("batch_size", 1)
@@ -1538,16 +1552,11 @@ def bench_train(seed: int = 0) -> list[str]:
                            for s in scheds)
         coll_bytes = 2.0 * total_events * num_params * 4
         write_bytes = float(len(points)) * rounds * n * num_params * 4 * 2
-        terms = {"compute": grid_flops / PEAK_FLOPS_BF16,
-                 "memory": write_bytes / HBM_BW,
-                 "collective": coll_bytes / ICI_BW}
         fam_entry["executables"] = [{
             "executable": f"train_{fam}_grid", "method": "analytic",
             "flops": grid_flops, "write_bytes": write_bytes,
             "collective_bytes": coll_bytes,
-            "compute_s": terms["compute"], "memory_s": terms["memory"],
-            "collective_s": terms["collective"],
-            "bottleneck": max(terms, key=terms.get)}]
+            **_roofline(grid_flops, write_bytes, coll_bytes)}]
 
         report["families"][fam] = fam_entry
         rows.append(f"train_{fam}_dispatch,{cold_us:.0f},"
@@ -1930,6 +1939,8 @@ def main() -> None:
                     help="CI-sized sweeps (n=16, fewer rounds/families/"
                          "channel points) — for the scenario-smoke jobs")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.small:
         _TOPO_BENCH.update(n=16, rounds=60, seeds=2,
                            families=["ring", "complete"])

@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Algorithm, Simulator, World, build_graph, worker_mean
 from repro.data import SyntheticCIFAR
 from repro.models.resnet import init_resnet, resnet8_cifar, resnet_loss
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--batch-size", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = resnet8_cifar()
     stream = SyntheticCIFAR(batch_size=args.batch_size, noise=0.5)

@@ -13,6 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import Algorithm, Simulator, World, ring_graph
 from repro.data import LMTaskStream
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("nano-lm", reduced=not args.full)
     model = Model(cfg)

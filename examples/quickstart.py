@@ -14,11 +14,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (AdaptiveDefense, ByzantineEdges, ChannelModel,
                         DelayProcess, PhaseSwitch, Simulator, WorkerModel,
                         World, WorldSweep, hypercube_graph,
                         params_from_graph, ring_graph, worker_mean)
 
+enable_compile_cache()
 N_WORKERS, DIM, ROUNDS = 16, 64, 300
 
 # each worker i minimizes f_i(x) = ||x - b_i||^2 / 2; the consensus optimum

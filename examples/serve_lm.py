@@ -5,12 +5,14 @@ that never stop averaging, surviving a mid-serve churn kill.
 """
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.nano_lm import reduced
 from repro.core import (Algorithm, ChannelModel, DelayProcess, PhaseSwitch,
                         ServeLoad, World, ring_graph)
 from repro.launch.fleet import GossipFleet
 from repro.models import Model
 
+enable_compile_cache()
 model = Model(reduced())
 params = model.init(jax.random.PRNGKey(0))
 

@@ -16,10 +16,41 @@ import re
 
 import numpy as np
 
-# TPU v5e per-chip constants (launch/mesh.py mirrors these)
-PEAK_FLOPS_BF16 = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float   # FLOP/s
+    hbm_bw: float       # B/s
+    ici_bw: float       # B/s per chip-to-chip link
+    source: str
+
+
+V5E = "TPU v5 lite"   # jax's device_kind for a TPU v5e chip
+
+# keyed by ``jax.devices()[0].device_kind``
+PEAKS = {
+    V5E: DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect over 4 links
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI'),
+}
+
+
+def device_peaks(kind: str | None = None) -> DevicePeaks:
+    """Peaks of device kind ``kind`` (default: the live device's).  A kind
+    not in ``PEAKS`` raises: there is no default chip."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -81,18 +112,19 @@ class RooflineReport:
     collective_detail: dict
     model_flops_total: float  # analytic 6ND (global)
     peak_memory_per_device: float
+    peaks: DevicePeaks
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -125,7 +157,8 @@ class RooflineReport:
 
 def roofline_terms(*, arch: str, shape: str, mesh_name: str, chips: int,
                    cost: dict, hlo_text: str, model_flops_total: float,
-                   peak_memory: float) -> RooflineReport:
+                   peak_memory: float, peaks: DevicePeaks
+                   ) -> RooflineReport:
     coll = collective_bytes_from_hlo(hlo_text)
     return RooflineReport(
         arch=arch, shape=shape, mesh=mesh_name, chips=chips,
@@ -135,4 +168,5 @@ def roofline_terms(*, arch: str, shape: str, mesh_name: str, chips: int,
         collective_detail=coll,
         model_flops_total=model_flops_total,
         peak_memory_per_device=peak_memory,
+        peaks=peaks,
     )

@@ -36,6 +36,7 @@ from ..kernels.a2cid2_mixing.ops import (channel_event_local,
                                          channel_event_worlds,
                                          gossip_event_stacked,
                                          gossip_event_worlds, p2p_mix_event)
+from ..kernels.a2cid2_mixing.ref import take_partner_rows, take_pool_rows
 from .a2cid2 import A2CiD2Params, apply_mixing
 from .flatbuf import (FlatLayout, ring_init, ring_init_worlds, ring_push,
                       ring_push_worlds, ring_read, ring_read_worlds)
@@ -329,7 +330,7 @@ class FlatGossipEngine:
         staleness against its own (B, H, Ws, D) ring, so the value that
         crosses the permute ring is bitwise the one the single-device
         ``ring_read_worlds`` gather would have produced."""
-        fresh = jnp.take_along_axis(bx, rows[:, :, None], axis=1)
+        fresh = take_partner_rows(bx, rows)
         if ring is None:
             return fresh
         h = ring.shape[1]
@@ -345,6 +346,5 @@ class FlatGossipEngine:
         buffer: cross rows read ``pool[hop, :, pos]`` (the block published
         by the source shard), intra/idle rows keep the shard-local gather
         ``xp_local``."""
-        b_idx = jnp.arange(pool.shape[1])[:, None]
-        xp_cross = pool[hop, b_idx, pos]
+        xp_cross = take_pool_rows(pool, hop, pos)
         return jnp.where(is_cross[:, :, None], xp_cross, xp_local)

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.a2cid2_mixing.ref import take_rows
+
 PyTree = Any
 
 LANE = 128  # TPU lane width; last-dim tiles are multiples of this
@@ -277,4 +279,4 @@ def ring_pool_exchange(vals: jax.Array, axis_name: str,
     pool = jax.lax.all_gather(vals, axis_name)    # (NS, ...) by source
     me = jax.lax.axis_index(axis_name)
     hops = (me - jnp.arange(n_shards, dtype=jnp.int32)) % n_shards
-    return pool[hops]
+    return take_rows(pool, hops)

@@ -834,6 +834,15 @@ class Simulator:
             else self._run_coalesced_jit
         return fn(state, stream_arrays)
 
+    def schedule_executable(self, state: SimState, sched: Schedule):
+        """The exact (jitted replay, argument tuple) ``run_schedule``
+        dispatches for a plain (channel-free) schedule on the engine path,
+        in this simulator's donation flavor — for AOT inspection of the
+        hot path (``fn.lower(*args).compile()``) without a replay."""
+        fn = self._run_coalesced_dnt if self.donate \
+            else self._run_coalesced_jit
+        return fn, (self, state, self.coalesced_arrays(state, sched))
+
     def run_world(self, state: SimState, world, rounds: int | None = None, *,
                   seed: int = 0, engine: bool = True):
         """Compile a declarative ``world.World`` and replay it.

@@ -22,7 +22,14 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class LMTaskStream:
-    """Order-1 Markov-chain token stream (fixed random transition matrix)."""
+    """Order-1 Markov-chain token stream (fixed random transition matrix).
+
+    Row ``t`` of the transition logits is i.i.d. Gumbel noise over the
+    vocabulary, drawn from ``fold_in(PRNGKey(seed), t)`` and divided by
+    ``concentration``.  Rows are drawn when a token needs them, so the
+    stream holds no (V, V) matrix: at a 32,000-token vocabulary that
+    matrix alone would be 4.1 GB of f32 on the device.
+    """
 
     vocab_size: int
     seq_len: int
@@ -30,19 +37,29 @@ class LMTaskStream:
     concentration: float = 0.3  # lower = more predictable
     seed: int = 1234
 
+    def row_logits(self, tok: jax.Array) -> jax.Array:
+        """Transition logits out of token(s) ``tok``: shape tok.shape + (V,)."""
+        base = jax.random.PRNGKey(self.seed)
+
+        def row(t):
+            k = jax.random.fold_in(base, t)
+            return jax.random.gumbel(k, (self.vocab_size,), jnp.float32)
+
+        flat = jnp.reshape(jnp.asarray(tok, jnp.int32), (-1,))
+        rows = jax.vmap(row)(flat) / self.concentration
+        return jnp.reshape(rows, jnp.shape(tok) + (self.vocab_size,))
+
     def transition_logits(self) -> jax.Array:
-        rng = np.random.default_rng(self.seed)
-        logits = rng.gumbel(size=(self.vocab_size, self.vocab_size))
-        return jnp.asarray(logits / self.concentration, jnp.float32)
+        """The whole (V, V) matrix — for small vocabularies and analysis."""
+        return self.row_logits(jnp.arange(self.vocab_size))
 
     def sample(self, key: jax.Array) -> dict:
         """Returns {"inputs": (B,S) int32, "labels": (B,S) int32}."""
-        logits = self.transition_logits()
         k0, k1 = jax.random.split(key)
         first = jax.random.randint(k0, (self.batch_size,), 0, self.vocab_size)
 
         def step(tok, k):
-            nxt = jax.random.categorical(k, logits[tok], axis=-1)
+            nxt = jax.random.categorical(k, self.row_logits(tok), axis=-1)
             return nxt, nxt
 
         keys = jax.random.split(k1, self.seq_len)
@@ -54,7 +71,7 @@ class LMTaskStream:
 
     def bayes_ce(self) -> float:
         """Entropy rate of the chain = minimum achievable CE."""
-        logits = np.asarray(self.transition_logits())
+        logits = np.asarray(self.transition_logits(), np.float64)
         p = np.exp(logits - logits.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
         # stationary distribution via power iteration

@@ -26,34 +26,38 @@ partner's CURRENT (already-mixed) x, so no partner x~ read is needed:
     out_xt = xt1 - c * (xt1 - x1)
 
 Unfused, an event is 2 elementwise passes over 3 full parameter-sized
-tensors (6 reads + 4 writes of HBM).  Either fused kernel does 3 reads +
-2 writes — a 2x HBM-traffic reduction on the gossip step, which matters
-because the gossip event IS the paper's unit of communication cost.
-
-Layout: ``mixing_p2p``/``p2p_mixing`` take flat (N,) vectors tiled to
-(BLOCK,) VMEM blocks with `dt` a scalar in SMEM.  ``mixing_gossip_stacked``
-takes worker-stacked (W, D) buffers on a 2-D grid (workers x D-blocks); the
-partner index and per-worker dt vectors are scalar-prefetched so the partner
-row gather is resolved to a static block index before each grid step runs.
+tensors (6 reads + 4 writes of HBM).  The fused kernels read x and x~
+once and write both in place.
 
 ``channel_gossip_stacked`` is the unreliable-channel variant (DESIGN.md
 §10): partner values arrive pre-gathered (fresh row or ring-buffer stale
-snapshot — an XLA gather outside the kernel), a prefetched per-worker
-``corrupt`` multiplier offset models Byzantine messages, and the robust
-aggregation rides in two forms — a prefetched per-worker ``mscale``
-(norm-trim rejection / norm-clip rescale, derived by the caller from
-||m|| in one fused reduce) and a static coordinate ``clip``:
+snapshot — an XLA gather outside the kernel), a per-worker ``corrupt``
+multiplier offset models Byzantine messages, and the robust aggregation
+rides in two forms — a per-worker ``mscale`` (norm-trim rejection /
+norm-clip rescale, derived by the caller from ||m|| in one fused reduce)
+and a static coordinate ``clip``:
 
     m   = clip((x - (1 + corrupt) * xp) * mscale, +-tau)
     ...same p2p-then-mix tail as above...
 
 ``mixing_gossip_worlds`` / ``channel_gossip_worlds`` are the world-batched
-twins (DESIGN.md §11): the batch is the leading (slowest) grid axis over
-(B, W, D) buffers, and the A2CiD2 dynamics (eta, alpha, alpha_t) ride in
-as prefetched (B,) per-world scalars instead of static Python floats — so
-baseline and accelerated worlds, and every point of a sweep grid, share
-ONE kernel trace.  Per world the arithmetic is bitwise the serial
-kernel's (f32 param rounding commutes with the power-of-two multiplies).
+forms (DESIGN.md §11): B worlds' (B, W, D) banks on one grid, with the
+A2CiD2 dynamics per world, so baseline and accelerated worlds, and every
+point of a sweep grid, share ONE kernel trace.  The stacked and flat
+kernels are these at B = 1 (and W = 1).
+
+Layout (DESIGN.md §17).  Every kernel runs on a (B, W, D) view and tiles
+it in (W, block) slabs: all worker rows of one column range per grid
+step.  A (W, D) f32 bank is tiled (4, 128) or (8, 128) in HBM, so a
+row-blocked (W, D/128, 128) view would force XLA to relayout the whole
+bank on every call; the (W, block) slab needs no copy, because its row
+dimension is the whole worker axis.  The partner gather is then a
+row-to-row copy inside VMEM (each slab holds every partner row), so a
+batch reads x and x~ once, writes both, and aliases both in place: no
+other grid step touches that column range.  The mixing coefficient c,
+the dynamics and the channel scalars arrive as one (W, K) coefficient
+block per world, computed by XLA before the call (the TPU's scalar unit
+has no exp); the partner indices are scalar-prefetched into SMEM.
 """
 from __future__ import annotations
 
@@ -64,157 +68,216 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 64 * 1024  # 64k elems: 3 in + 2 out bf16 blocks = 640 KiB of VMEM
+LANE = 128
+# bytes of one (W, block) slab of one operand (counted at f32 width)
+BLOCK_BYTES = 512 * 1024
 
-# stacked kernel: (1, BLOCK_D) blocks; 4 in + 2 out f32 blocks = 384 KiB VMEM
-BLOCK_D = 16 * 1024
+# coefficient columns
+_C, _ALPHA, _ALPHA_T, _CADV, _MSCALE = range(5)
 
 
-def _mixing_kernel(dt_ref, x_ref, xt_ref, xp_ref, out_x_ref, out_xt_ref, *,
-                   eta: float, alpha: float, alpha_t: float):
+def _block_cols(w: int, d: int) -> int:
+    """Lane-axis block: about BLOCK_BYTES per slab, a multiple of 128, or
+    the whole of ``d`` when that is smaller (then it equals the array
+    dimension, which the TPU's tiling rule also accepts)."""
+    cols = max(LANE, BLOCK_BYTES // (4 * w) // LANE * LANE)
+    return d if d <= cols else cols
+
+
+def _coef_dtype(dtype) -> jnp.dtype:
+    """Coefficients keep f64 for f64 banks (x64 runs), else f32 — each is
+    cast to the bank dtype in-kernel, as a weak Python scalar would be."""
+    return jnp.dtype(jnp.float64 if jnp.dtype(dtype) == jnp.float64
+                     else jnp.float32)
+
+
+def mixing_coef(eta, dt) -> jax.Array:
+    """c = (1 - exp(-2 eta dt)) / 2 in f32, shaped like ``dt``."""
+    eta = jnp.asarray(eta, jnp.float32)
+    eta = jnp.reshape(eta, eta.shape + (1,) * (jnp.ndim(dt) - eta.ndim))
+    return 0.5 * (1.0 - jnp.exp(-2.0 * eta * jnp.asarray(dt, jnp.float32)))
+
+
+def _coefs(dtype, cols) -> jax.Array:
+    """Stack per-(world, worker) coefficient columns, each broadcast to
+    (B, W), into the (B, W, K) block the kernels read."""
+    shape = jnp.broadcast_shapes(*(jnp.shape(c) for c in cols))
+    cd = _coef_dtype(dtype)
+    return jnp.stack([jnp.broadcast_to(jnp.asarray(c).astype(cd), shape)
+                      for c in cols], axis=-1)
+
+
+def _per_world(v, b: int) -> jax.Array:
+    """(B,) or scalar per-world value -> (B, 1) for broadcasting to (B, W)."""
+    return jnp.reshape(jnp.broadcast_to(jnp.asarray(v), (b,)), (b, 1))
+
+
+# ---------------------------------------------------------------------------
+# kernel bodies: one (W, block) slab of one world per grid step
+# ---------------------------------------------------------------------------
+
+def _col(coef, k: int, x):
+    """Coefficient column k broadcast to ``x``'s slab, then cast to its
+    dtype (broadcast at 32 bits: Mosaic cannot broadcast a packed 16-bit
+    scalar over sublanes and lanes at once)."""
+    return jnp.broadcast_to(coef[:, k:k + 1], x.shape).astype(x.dtype)
+
+
+def _p2p_mix_tail(x, xt, m, coef, out_x_ref, out_xt_ref):
+    """out = mix_c(x - alpha m, x~ - alpha_t m), coefficients per row."""
+    x1 = x - _col(coef, _ALPHA, x) * m
+    xt1 = xt - _col(coef, _ALPHA_T, x) * m
+    c = _col(coef, _C, x)
+    d = xt1 - x1
+    out_x_ref[...] = x1 + c * d
+    out_xt_ref[...] = xt1 - c * d
+
+
+def _gossip_kernel(partner_ref, coef_ref, x_ref, xt_ref, out_x_ref,
+                   out_xt_ref, xp_ref, *src32):
+    """Plain p2p-then-mix: xp[w] = x[partner[b, w]], copied row by row
+    inside VMEM (16-bit banks gather through an f32 copy of the slab, as
+    Mosaic's dynamic row loads need 32-bit rows)."""
+    b = pl.program_id(0)
+    x = x_ref[...]
+    src = x_ref
+    if src32:
+        src = src32[0]
+        src[...] = x.astype(jnp.float32)
+
+    def row(w, carry):
+        xp_ref[pl.ds(w, 1), :] = src[pl.ds(partner_ref[b, w], 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, x.shape[0], row, 0)
+    xp = xp_ref[...].astype(x.dtype)
+    m = x - xp           # partner==w => xp==x => m==0 (idle worker no-op)
+    _p2p_mix_tail(x, xt_ref[...], m, coef_ref[...], out_x_ref, out_xt_ref)
+
+
+def _channel_kernel(coef_ref, x_ref, xp_ref, xt_ref, out_x_ref, out_xt_ref,
+                    *, clip):
+    """Channel p2p-then-mix on pre-gathered partner values."""
+    x = x_ref[...]
+    coef = coef_ref[...]
+    m = (x - _col(coef, _CADV, x) * xp_ref[...]) * _col(coef, _MSCALE, x)
+    if clip is not None:
+        m = jnp.clip(m, -clip, clip)
+    _p2p_mix_tail(x, xt_ref[...], m, coef, out_x_ref, out_xt_ref)
+
+
+def _mix_p2p_kernel(coef_ref, x_ref, xp_ref, xt_ref, out_x_ref,
+                    out_xt_ref):
+    """Mix-then-p2p order on pre-gathered partner values."""
     x = x_ref[...]
     xt = xt_ref[...]
-    xp = xp_ref[...]
-    dt = dt_ref[0]
-    c = 0.5 * (1.0 - jnp.exp(-2.0 * eta * dt)).astype(x.dtype)
+    coef = coef_ref[...]
+    c = _col(coef, _C, x)
     d = xt - x
     xm = x + c * d
     xtm = xt - c * d
-    m = xm - xp
-    out_x_ref[...] = xm - alpha * m
-    out_xt_ref[...] = xtm - alpha_t * m
+    m = xm - xp_ref[...]
+    out_x_ref[...] = xm - _col(coef, _ALPHA, x) * m
+    out_xt_ref[...] = xtm - _col(coef, _ALPHA_T, x) * m
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("eta", "alpha", "alpha_t", "interpret"))
-def mixing_p2p(x: jax.Array, x_tilde: jax.Array, x_partner: jax.Array,
-               dt: jax.Array, *, eta: float, alpha: float, alpha_t: float,
-               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Apply one fused (mix, p2p) event to flat parameter arrays.
+# ---------------------------------------------------------------------------
+# one pallas_call for every kernel
+# ---------------------------------------------------------------------------
 
-    x, x_tilde, x_partner: (N,) same dtype; dt: scalar f32.
+def _slab_call(kernel, x, x_tilde, x_partner, coef, partner, *,
+               name: str, interpret: bool, rows: int | None = None):
+    """Run ``kernel`` over (B, W, D) banks in (rows, block) slabs.
+
+    ``rows`` defaults to all of W (the plain kernel's partner gather needs
+    every row of its slab).  ``coef`` is (B, W, K), or (B, 1, K) when all
+    rows share it.  Operands are (coef, x, x_partner?, x_tilde) or, with a
+    scalar-prefetched (B, W) ``partner``, (partner, coef, x, x_tilde).
+    Outputs are (x, x~), aliased in place to the x and x~ inputs.
     """
-    n = x.shape[0]
-    block = min(BLOCK, n)
-    # pad to a multiple of the block
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, (0, pad))
-        x_tilde = jnp.pad(x_tilde, (0, pad))
-        x_partner = jnp.pad(x_partner, (0, pad))
-    grid = (x.shape[0] // block,)
-    dt_arr = jnp.reshape(dt.astype(jnp.float32), (1,))
-    kernel = functools.partial(_mixing_kernel, eta=eta, alpha=alpha,
-                               alpha_t=alpha_t)
-    out_x, out_xt = pl.pallas_call(
-        kernel,
+    b_dim, w_dim, d_dim = x.shape
+    rows = w_dim if rows is None else min(rows, w_dim)
+    block = _block_cols(rows, d_dim)
+    grid = (b_dim, pl.cdiv(w_dim, rows), pl.cdiv(d_dim, block))
+    n_pre = 0 if partner is None else 1
+    slab = pl.BlockSpec((None, rows, block),
+                        lambda b, i, j, *_: (b, i, j))
+    if coef.shape[1] == 1:
+        coef_spec = pl.BlockSpec((None, 1, coef.shape[-1]),
+                                 lambda b, i, j, *_: (b, 0, 0))
+    else:
+        coef_spec = pl.BlockSpec((None, rows, coef.shape[-1]),
+                                 lambda b, i, j, *_: (b, i, 0))
+    tensors = [x] if x_partner is None else [x, x_partner]
+    tensors.append(x_tilde)
+    scratch = []
+    if partner is not None:
+        wide = x.dtype.itemsize < 4
+        scratch.append(pltpu.VMEM((rows, block),
+                                  jnp.float32 if wide else x.dtype))
+        if wide:
+            scratch.append(pltpu.VMEM((rows, block), jnp.float32))
+    # double-buffered operand and output slabs plus the gather scratch
+    vmem_need = (2 * (len(tensors) + 2) + len(scratch)) * rows * block * 4
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pre,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # dt scalar, whole array
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-        ],
+        in_specs=[coef_spec] + [slab] * len(tensors),
+        out_specs=[slab, slab],
+        scratch_shapes=scratch,
+    )
+    args = ([] if partner is None else [partner.astype(jnp.int32)])
+    args += [coef] + tensors
+    x_pos, xt_pos = n_pre + 1, n_pre + len(tensors)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)] * 2,
+        input_output_aliases={x_pos: 0, xt_pos: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=max(32 << 20, 2 * vmem_need)),
         interpret=interpret,
-    )(dt_arr, x, x_tilde, x_partner)
-    if pad:
-        out_x = out_x[:n]
-        out_xt = out_xt[:n]
-    return out_x, out_xt
+        name=name,
+    )(*args)
+
+
+def _flat_call(kernel, x, x_tilde, x_partner, coef, *, name, interpret):
+    """Run a pre-gathered kernel on flat (N,) vectors.  A 128-aligned
+    vector is viewed as (N/128, 128) rows — the same bytes as its 1-D
+    tiling, so no copy — and tiled in BLOCK_BYTES row slabs."""
+    n = x.shape[0]
+    shape = (1, n // LANE, LANE) if n % LANE == 0 else (1, 1, n)
+    ox, ot = _slab_call(kernel, x.reshape(shape), x_tilde.reshape(shape),
+                        x_partner.reshape(shape), coef[None, None],
+                        None, name=name, interpret=interpret,
+                        rows=BLOCK_BYTES // (4 * LANE))
+    return ox.reshape(n), ot.reshape(n)
 
 
 # ---------------------------------------------------------------------------
-# p2p-then-mix order (flat vectors) — the event-engine group pass
+# public kernels
 # ---------------------------------------------------------------------------
 
-def _p2p_mixing_kernel(dt_ref, x_ref, xt_ref, xp_ref, out_x_ref, out_xt_ref,
-                       *, eta: float, alpha: float, alpha_t: float):
-    x = x_ref[...]
-    xt = xt_ref[...]
-    xp = xp_ref[...]
-    dt = dt_ref[0]
-    m = x - xp
-    x1 = x - alpha * m
-    xt1 = xt - alpha_t * m
-    c = (0.5 * (1.0 - jnp.exp(-2.0 * eta * dt))).astype(x.dtype)
-    d = xt1 - x1
-    out_x_ref[...] = x1 + c * d
-    out_xt_ref[...] = xt1 - c * d
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mixing_gossip_worlds(x: jax.Array, x_tilde: jax.Array,
+                         partner: jax.Array, dt_next: jax.Array,
+                         eta: jax.Array, alpha: jax.Array,
+                         alpha_t: jax.Array, *, interpret: bool = False
+                         ) -> tuple[jax.Array, jax.Array]:
+    """One coalesced gossip batch over B worlds' stacked buffers at once.
 
-
-@functools.partial(jax.jit,
-                   static_argnames=("eta", "alpha", "alpha_t", "interpret"))
-def p2p_mixing(x: jax.Array, x_tilde: jax.Array, x_partner: jax.Array,
-               dt_next: jax.Array, *, eta: float, alpha: float,
-               alpha_t: float, interpret: bool = False
-               ) -> tuple[jax.Array, jax.Array]:
-    """Fused p2p update followed by mixing for ``dt_next`` (flat vectors).
-
-    x, x_tilde, x_partner: (N,) same dtype; dt_next: scalar f32.
+    x, x_tilde: (B, W, D) same dtype; partner: (B, W) int32 (per-world
+    involutions, partner[b, w] == w for idle workers); dt_next: (B, W)
+    f32; eta/alpha/alpha_t: (B,) per-world dynamics — the batch mixes
+    baseline (eta 0) and accelerated worlds in ONE trace, which is what
+    makes a whole sweep family one compile + one dispatch.
     """
-    n = x.shape[0]
-    block = min(BLOCK, n)
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, (0, pad))
-        x_tilde = jnp.pad(x_tilde, (0, pad))
-        x_partner = jnp.pad(x_partner, (0, pad))
-    grid = (x.shape[0] // block,)
-    dt_arr = jnp.reshape(dt_next.astype(jnp.float32), (1,))
-    kernel = functools.partial(_p2p_mixing_kernel, eta=eta, alpha=alpha,
-                               alpha_t=alpha_t)
-    out_x, out_xt = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # dt scalar, whole array
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-        ],
-        interpret=interpret,
-    )(dt_arr, x, x_tilde, x_partner)
-    if pad:
-        out_x = out_x[:n]
-        out_xt = out_xt[:n]
-    return out_x, out_xt
-
-
-# ---------------------------------------------------------------------------
-# worker-stacked fused gossip batch (2-D grid, scalar-prefetched partners)
-# ---------------------------------------------------------------------------
-
-def _stacked_kernel(partner_ref, dt_ref, x_ref, xp_ref, xt_ref,
-                    out_x_ref, out_xt_ref, *, eta: float, alpha: float,
-                    alpha_t: float):
-    w = pl.program_id(0)
-    x = x_ref[...]
-    xp = xp_ref[...]
-    xt = xt_ref[...]
-    m = x - xp           # partner==w => xp==x => m==0 (idle worker no-op)
-    x1 = x - alpha * m
-    xt1 = xt - alpha_t * m
-    dt = dt_ref[w]
-    c = (0.5 * (1.0 - jnp.exp(-2.0 * eta * dt))).astype(x.dtype)
-    d = xt1 - x1
-    out_x_ref[...] = x1 + c * d
-    out_xt_ref[...] = xt1 - c * d
+    b = x.shape[0]
+    coef = _coefs(x.dtype, [mixing_coef(eta, dt_next), _per_world(alpha, b),
+                            _per_world(alpha_t, b)])
+    return _slab_call(_gossip_kernel, x, x_tilde, None, coef, partner,
+                      name="a2cid2_gossip", interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -228,179 +291,14 @@ def mixing_gossip_stacked(x: jax.Array, x_tilde: jax.Array,
 
     x, x_tilde: (W, D) same dtype; partner: (W,) int32 (partner[w] == w for
     idle workers); dt_next: (W,) f32 per-worker mixing horizon to the next
-    event (p2p-then-mix order, see module docstring).
-
-    The partner gather is resolved via scalar prefetch: the BlockSpec index
-    map reads partner[w] before the grid step runs, so the partner row block
-    arrives by regular (static-index) pipelining — no in-kernel gather.  Per
-    batch the kernel reads 3 state-sized buffers (x twice: self + partner
-    rows; x~ once) and writes 2.  x~ only ever reads its own row, so its
-    input buffer is aliased to the output in place; x cannot alias (another
-    grid step may still read row w as a partner after w is updated).
+    event (p2p-then-mix order, see module docstring).  The world-batched
+    kernel at B = 1.
     """
-    w_dim, d_dim = x.shape
-    block = min(BLOCK_D, d_dim)
-    pad = (-d_dim) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        x_tilde = jnp.pad(x_tilde, ((0, 0), (0, pad)))
-    grid = (w_dim, x.shape[1] // block)
-    partner = partner.astype(jnp.int32)
-    dt_next = dt_next.astype(jnp.float32)
-    kernel = functools.partial(_stacked_kernel, eta=eta, alpha=alpha,
-                               alpha_t=alpha_t)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # partner, dt_next
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block), lambda w, d, p, t: (w, d)),
-            pl.BlockSpec((1, block), lambda w, d, p, t: (p[w], d)),
-            pl.BlockSpec((1, block), lambda w, d, p, t: (w, d)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda w, d, p, t: (w, d)),
-            pl.BlockSpec((1, block), lambda w, d, p, t: (w, d)),
-        ],
-    )
-    out_x, out_xt = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-        ],
-        # inputs are (partner, dt, x, x, xt): alias xt -> out_xt in place
-        input_output_aliases={} if interpret else {4: 1},
-        interpret=interpret,
-    )(partner, dt_next, x, x, x_tilde)
-    if pad:
-        out_x = out_x[:, :d_dim]
-        out_xt = out_xt[:, :d_dim]
-    return out_x, out_xt
-
-
-# ---------------------------------------------------------------------------
-# world-batched fused gossip batch (3-D grid; many-worlds replay, §11)
-# ---------------------------------------------------------------------------
-
-def _worlds_kernel(partner_ref, dt_ref, eta_ref, alpha_ref, alphat_ref,
-                   x_ref, xp_ref, xt_ref, out_x_ref, out_xt_ref):
-    b = pl.program_id(0)
-    w = pl.program_id(1)
-    x = x_ref[...]
-    xp = xp_ref[...]
-    xt = xt_ref[...]
-    m = x - xp           # partner==w => xp==x => m==0 (idle worker no-op)
-    alpha = alpha_ref[b].astype(x.dtype)
-    alpha_t = alphat_ref[b].astype(x.dtype)
-    x1 = x - alpha * m
-    xt1 = xt - alpha_t * m
-    c = (0.5 * (1.0 - jnp.exp(-2.0 * eta_ref[b] * dt_ref[b, w]))
-         ).astype(x.dtype)
-    d = xt1 - x1
-    out_x_ref[...] = x1 + c * d
-    out_xt_ref[...] = xt1 - c * d
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def mixing_gossip_worlds(x: jax.Array, x_tilde: jax.Array,
-                         partner: jax.Array, dt_next: jax.Array,
-                         eta: jax.Array, alpha: jax.Array,
-                         alpha_t: jax.Array, *, interpret: bool = False
-                         ) -> tuple[jax.Array, jax.Array]:
-    """One coalesced gossip batch over B worlds' stacked buffers at once.
-
-    x, x_tilde: (B, W, D) same dtype; partner: (B, W) int32 (per-world
-    involutions); dt_next: (B, W) f32; eta/alpha/alpha_t: (B,) f32
-    per-world dynamics riding in as prefetched scalars — the batch mixes
-    baseline (eta 0) and accelerated worlds in ONE trace, which is what
-    makes a whole sweep family one compile + one dispatch.
-
-    Same structure as ``mixing_gossip_stacked`` with the batch as the
-    leading (slowest) grid axis: the partner row gather resolves to a
-    static (b, partner[b, w], d) block index via scalar prefetch, x~ only
-    reads its own row and aliases its output in place, and each grid step
-    stays 3 state reads + 2 writes.
-    """
-    b_dim, w_dim, d_dim = x.shape
-    block = min(BLOCK_D, d_dim)
-    pad = (-d_dim) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-        x_tilde = jnp.pad(x_tilde, ((0, 0), (0, 0), (0, pad)))
-    grid = (b_dim, w_dim, x.shape[2] // block)
-    partner = partner.astype(jnp.int32)
-    dt_next = dt_next.astype(jnp.float32)
-    # eta joins the f32 mixing-coefficient pipeline (what the serial
-    # kernel computes c in); alpha/alpha_t keep their precision and cast
-    # straight to the buffer dtype in-kernel (weak-scalar semantics)
-    pw = [jnp.asarray(eta, jnp.float32), jnp.asarray(alpha),
-          jnp.asarray(alpha_t)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,  # partner, dt_next, eta, alpha, alpha_t
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, p, t, e, a, at: (b, w, d)),
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, p, t, e, a, at: (b, p[b, w], d)),
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, p, t, e, a, at: (b, w, d)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, p, t, e, a, at: (b, w, d)),
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, p, t, e, a, at: (b, w, d)),
-        ],
-    )
-    out_x, out_xt = pl.pallas_call(
-        _worlds_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-        ],
-        # inputs are (partner, dt, eta, alpha, alpha_t, x, x, xt):
-        # alias xt -> out_xt in place (x cannot alias: later grid steps
-        # may still read any row as a partner)
-        input_output_aliases={} if interpret else {7: 1},
-        interpret=interpret,
-    )(partner, dt_next, *pw, x, x, x_tilde)
-    if pad:
-        out_x = out_x[:, :, :d_dim]
-        out_xt = out_xt[:, :, :d_dim]
-    return out_x, out_xt
-
-
-def _channel_worlds_kernel(corrupt_ref, mscale_ref, dt_ref, eta_ref,
-                           alpha_ref, alphat_ref, x_ref, xp_ref, xt_ref,
-                           out_x_ref, out_xt_ref, *rej_ref, clip):
-    b = pl.program_id(0)
-    w = pl.program_id(1)
-    x = x_ref[...]
-    xp = xp_ref[...]
-    xt = xt_ref[...]
-    cadv = (1.0 + corrupt_ref[b, w]).astype(x.dtype)
-    m = (x - cadv * xp) * mscale_ref[b, w].astype(x.dtype)
-    if clip is not None:
-        m = jnp.clip(m, -clip, clip)
-    alpha = alpha_ref[b].astype(x.dtype)
-    alpha_t = alphat_ref[b].astype(x.dtype)
-    x1 = x - alpha * m
-    xt1 = xt - alpha_t * m
-    c = (0.5 * (1.0 - jnp.exp(-2.0 * eta_ref[b] * dt_ref[b, w]))
-         ).astype(x.dtype)
-    d = xt1 - x1
-    out_x_ref[...] = x1 + c * d
-    out_xt_ref[...] = xt1 - c * d
-    if rej_ref:
-        # per-event rejection mask (self-healing defense, DESIGN.md §12):
-        # 1.0 where the robust scale zeroed the exchange; the (1, 1, 1)
-        # output block is constant along the d axis, so every d-step
-        # rewrites the same value
-        rej_ref[0][...] = (mscale_ref[b, w] == 0.0).astype(
-            jnp.float32).reshape(1, 1, 1)
+    ox, ot = mixing_gossip_worlds(
+        x[None], x_tilde[None], jnp.asarray(partner)[None],
+        jnp.asarray(dt_next)[None], eta, alpha, alpha_t,
+        interpret=interpret)
+    return ox[0], ot[0]
 
 
 @functools.partial(jax.jit, static_argnames=("clip", "want_rej",
@@ -417,105 +315,23 @@ def channel_gossip_worlds(x: jax.Array, x_tilde: jax.Array,
 
     x, x_tilde, x_partner: (B, W, D) same dtype — partner values arrive
     PRE-GATHERED per world (fresh rows or (B, H, W, D) ring snapshots);
-    corrupt, mscale, dt_next: (B, W) f32; eta/alpha/alpha_t: (B,) f32
-    per-world dynamics; ``clip`` the static coordinate-clip rule.  All
-    per-(world, worker) scalars ride the prefetch lane, so every tensor
-    operand streams with static block indices exactly like the serial
-    channel kernel — 3 state reads + 2 writes per grid step, x~ aliased.
+    corrupt, mscale, dt_next: (B, W) f32; eta/alpha/alpha_t: (B,)
+    per-world dynamics; ``clip`` the static coordinate-clip rule.
     ``want_rej`` (static) adds a third output: the (B, W) f32 rejection
-    mask ``mscale == 0`` the self-healing defense's trust loop consumes
-    (a (1, 1, 1)-blocked scalar lane, negligible extra traffic).
+    mask ``mscale == 0`` the self-healing defense's trust loop consumes.
     """
-    b_dim, w_dim, d_dim = x.shape
-    block = min(BLOCK_D, d_dim)
-    pad = (-d_dim) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-        x_tilde = jnp.pad(x_tilde, ((0, 0), (0, 0), (0, pad)))
-        x_partner = jnp.pad(x_partner, ((0, 0), (0, 0), (0, pad)))
-    grid = (b_dim, w_dim, x.shape[2] // block)
-    pw = [jnp.asarray(v, jnp.float32)
-          for v in (corrupt, mscale, dt_next, eta)]
-    pw += [jnp.asarray(alpha), jnp.asarray(alpha_t)]
-    kernel = functools.partial(_channel_worlds_kernel, clip=clip)
-    out_specs = [
-        pl.BlockSpec((1, 1, block),
-                     lambda b, w, d, c, s, t, e, a, at: (b, w, d)),
-        pl.BlockSpec((1, 1, block),
-                     lambda b, w, d, c, s, t, e, a, at: (b, w, d)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct(x.shape, x.dtype),
-        jax.ShapeDtypeStruct(x.shape, x.dtype),
-    ]
+    b = x.shape[0]
+    mscale = jnp.asarray(mscale, jnp.float32)
+    coef = _coefs(x.dtype, [
+        mixing_coef(eta, dt_next), _per_world(alpha, b),
+        _per_world(alpha_t, b),
+        1.0 + jnp.asarray(corrupt, jnp.float32), mscale])
+    out_x, out_xt = _slab_call(
+        functools.partial(_channel_kernel, clip=clip), x, x_tilde,
+        x_partner, coef, None, name="a2cid2_channel", interpret=interpret)
     if want_rej:
-        out_specs.append(pl.BlockSpec(
-            (1, 1, 1), lambda b, w, d, c, s, t, e, a, at: (b, w, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((b_dim, w_dim, 1), jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,  # corrupt, mscale, dt, eta, alpha, alpha_t
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, c, s, t, e, a, at: (b, w, d)),
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, c, s, t, e, a, at: (b, w, d)),
-            pl.BlockSpec((1, 1, block),
-                         lambda b, w, d, c, s, t, e, a, at: (b, w, d)),
-        ],
-        out_specs=out_specs,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        # inputs are (corrupt, mscale, dt, eta, alpha, alpha_t, x, xp, xt):
-        # alias xt -> out_xt in place
-        input_output_aliases={} if interpret else {8: 1},
-        interpret=interpret,
-    )(*pw, x, x_partner, x_tilde)
-    out_x, out_xt = outs[0], outs[1]
-    if pad:
-        out_x = out_x[:, :, :d_dim]
-        out_xt = out_xt[:, :, :d_dim]
-    if want_rej:
-        return out_x, out_xt, outs[2][:, :, 0]
+        return out_x, out_xt, (mscale == 0.0).astype(jnp.float32)
     return out_x, out_xt
-
-
-# ---------------------------------------------------------------------------
-# unreliable-channel fused batch (robust m-term; DESIGN.md §10)
-# ---------------------------------------------------------------------------
-
-def _channel_kernel(corrupt_ref, mscale_ref, dt_ref, x_ref, xp_ref, xt_ref,
-                    out_x_ref, out_xt_ref, *rej_ref, eta: float,
-                    alpha: float, alpha_t: float, clip):
-    w = pl.program_id(0)
-    x = x_ref[...]
-    xp = xp_ref[...]
-    xt = xt_ref[...]
-    # received value: (1 + corrupt) * xp — honest rows have corrupt == 0,
-    # so the multiply is an exact identity (1.0 * xp == xp bitwise); the
-    # robust trim/clip scale (from the delta's norm, computed by the caller
-    # in one fused reduce) rides in the same way, 1.0 for accepted deltas
-    cadv = (1.0 + corrupt_ref[w]).astype(x.dtype)
-    m = (x - cadv * xp) * mscale_ref[w].astype(x.dtype)
-    if clip is not None:
-        m = jnp.clip(m, -clip, clip)  # in-kernel coordinate-clip rule
-    x1 = x - alpha * m
-    xt1 = xt - alpha_t * m
-    dt = dt_ref[w]
-    c = (0.5 * (1.0 - jnp.exp(-2.0 * eta * dt))).astype(x.dtype)
-    d = xt1 - x1
-    out_x_ref[...] = x1 + c * d
-    out_xt_ref[...] = xt1 - c * d
-    if rej_ref:
-        # per-event rejection mask (self-healing defense, DESIGN.md §12):
-        # the (1, 1) block is constant along the d axis, so every d-step
-        # rewrites the same scalar
-        rej_ref[0][...] = (mscale_ref[w] == 0.0).astype(
-            jnp.float32).reshape(1, 1)
 
 
 @functools.partial(jax.jit,
@@ -533,66 +349,46 @@ def channel_gossip_stacked(x: jax.Array, x_tilde: jax.Array,
     x, x_tilde, x_partner: (W, D) same dtype; corrupt, mscale, dt_next:
     (W,) f32.  ``x_partner`` arrives PRE-GATHERED: staleness resolution
     (current row vs ring-buffer snapshot) is a data question the engine
-    answers with one XLA gather before the sweep, so the kernel needs no
-    in-grid partner indirection — all five tensor operands stream with
-    static block indices.  ``corrupt``/``mscale``/``dt_next`` ride in as
-    prefetched per-worker scalars (``mscale`` is the norm-trim/clip robust
-    scale, 1.0 = accept); ``clip`` (static) is the in-kernel
-    coordinate-clip rule.  Traffic is the same 3 reads + 2 writes of state
-    as the clean kernel (the caller's norm reduce for mscale adds 2 reads
-    when a norm rule is on).  x~ only ever reads its own row and is
-    aliased in place; x and x_partner are distinct buffers here, so x
-    cannot alias.  ``want_rej`` (static) adds a third output: the (W,)
-    f32 rejection mask ``mscale == 0`` the self-healing defense's trust
-    loop consumes (a (1, 1)-blocked scalar lane).
+    answers with one XLA gather before the sweep.  ``mscale`` is the
+    norm-trim/clip robust scale (1.0 = accept); ``clip`` (static) is the
+    in-kernel coordinate-clip rule.  ``want_rej`` (static) adds the (W,)
+    f32 rejection mask ``mscale == 0``.  The world-batched kernel at
+    B = 1.
     """
-    w_dim, d_dim = x.shape
-    block = min(BLOCK_D, d_dim)
-    pad = (-d_dim) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        x_tilde = jnp.pad(x_tilde, ((0, 0), (0, pad)))
-        x_partner = jnp.pad(x_partner, ((0, 0), (0, pad)))
-    grid = (w_dim, x.shape[1] // block)
-    corrupt = corrupt.astype(jnp.float32)
-    mscale = mscale.astype(jnp.float32)
-    dt_next = dt_next.astype(jnp.float32)
-    kernel = functools.partial(_channel_kernel, eta=eta, alpha=alpha,
-                               alpha_t=alpha_t, clip=clip)
-    out_specs = [
-        pl.BlockSpec((1, block), lambda w, d, c, s, t: (w, d)),
-        pl.BlockSpec((1, block), lambda w, d, c, s, t: (w, d)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct(x.shape, x.dtype),
-        jax.ShapeDtypeStruct(x.shape, x.dtype),
-    ]
-    if want_rej:
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda w, d, c, s, t: (w, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((w_dim, 1), jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # corrupt, mscale, dt_next
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block), lambda w, d, c, s, t: (w, d)),
-            pl.BlockSpec((1, block), lambda w, d, c, s, t: (w, d)),
-            pl.BlockSpec((1, block), lambda w, d, c, s, t: (w, d)),
-        ],
-        out_specs=out_specs,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        # inputs are (corrupt, mscale, dt, x, xp, xt): alias xt -> out_xt
-        input_output_aliases={} if interpret else {5: 1},
-        interpret=interpret,
-    )(corrupt, mscale, dt_next, x, x_partner, x_tilde)
-    out_x, out_xt = outs[0], outs[1]
-    if pad:
-        out_x = out_x[:, :d_dim]
-        out_xt = out_xt[:, :d_dim]
-    if want_rej:
-        return out_x, out_xt, outs[2][:, 0]
-    return out_x, out_xt
+    outs = channel_gossip_worlds(
+        x[None], x_tilde[None], x_partner[None], jnp.asarray(corrupt)[None],
+        jnp.asarray(mscale)[None], jnp.asarray(dt_next)[None], eta, alpha,
+        alpha_t, clip=clip, want_rej=want_rej, interpret=interpret)
+    return tuple(o[0] for o in outs)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("eta", "alpha", "alpha_t", "interpret"))
+def p2p_mixing(x: jax.Array, x_tilde: jax.Array, x_partner: jax.Array,
+               dt_next: jax.Array, *, eta: float, alpha: float,
+               alpha_t: float, interpret: bool = False
+               ) -> tuple[jax.Array, jax.Array]:
+    """Fused p2p update followed by mixing for ``dt_next`` (flat vectors).
+
+    x, x_tilde, x_partner: (N,) same dtype; dt_next: scalar f32.  The
+    channel kernel at B = W = 1 with an honest, accepted read.
+    """
+    coef = _coefs(x.dtype, [mixing_coef(eta, dt_next), alpha, alpha_t,
+                            1.0, 1.0])
+    return _flat_call(functools.partial(_channel_kernel, clip=None), x,
+                      x_tilde, x_partner, coef, name="a2cid2_p2p_mixing",
+                      interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("eta", "alpha", "alpha_t", "interpret"))
+def mixing_p2p(x: jax.Array, x_tilde: jax.Array, x_partner: jax.Array,
+               dt: jax.Array, *, eta: float, alpha: float, alpha_t: float,
+               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+    """Apply one fused (mix, p2p) event to flat parameter arrays.
+
+    x, x_tilde, x_partner: (N,) same dtype; dt: scalar f32.
+    """
+    coef = _coefs(x.dtype, [mixing_coef(eta, dt), alpha, alpha_t])
+    return _flat_call(_mix_p2p_kernel, x, x_tilde, x_partner, coef,
+                      name="a2cid2_mixing_p2p", interpret=interpret)

@@ -1,8 +1,57 @@
 """Pure-jnp oracle for the fused A2CiD2 gossip-event kernel."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+
+# Rows at least this wide are gathered by one dynamic slice per row.  The
+# TPU compiler's time for an XLA gather grows faster than its row width
+# (v5e: 0.7 s at 2^20 f32 columns, 14.5 s at 2^25, over 5 minutes at
+# nano-LM's 128,404,224), while a loop of row copies compiles in about a
+# second and moves the same bytes.  Narrower rows keep the gather, whose
+# one op beats a W-step loop on small replicas.
+WIDE_ROW = 1 << 20
+
+
+def take_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` along axis 0 (whole rows), exact for any row width."""
+    if math.prod(x.shape[1:]) < WIDE_ROW:
+        return jnp.take(x, idx, axis=0)
+    return jax.lax.map(
+        lambda i: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
+        jnp.asarray(idx, jnp.int32))
+
+
+def take_partner_rows(x: jax.Array, partner: jax.Array) -> jax.Array:
+    """Per-world partner rows of (B, W, ...) ``x``: out[b, w] =
+    x[b, partner[b, w]]."""
+    partner = jnp.asarray(partner, jnp.int32)
+    if math.prod(x.shape[2:]) < WIDE_ROW:
+        return jnp.take_along_axis(
+            x, partner.reshape(partner.shape + (1,) * (x.ndim - 2)), axis=1)
+    return jax.lax.map(lambda xp: take_rows(*xp), (x, partner))
+
+
+def take_pool_rows(pool: jax.Array, hop: jax.Array, pos: jax.Array
+                   ) -> jax.Array:
+    """Rows of an (H, B, P, ...) pool: out[b, w] = pool[hop[b, w], b,
+    pos[b, w]], with (B, W) ``hop`` and ``pos``."""
+    hop = jnp.asarray(hop, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    if math.prod(pool.shape[3:]) < WIDE_ROW:
+        return pool[hop, jnp.arange(pool.shape[1])[:, None], pos]
+
+    def row(b, h, p):
+        at = jax.lax.dynamic_index_in_dim
+        return at(at(at(pool, h, 0, False), b, 0, False), p, 0, False)
+
+    b_idx = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    return jax.lax.map(
+        lambda bhp: jax.lax.map(lambda hp: row(bhp[0], *hp),
+                                (bhp[1], bhp[2])),
+        (b_idx, hop, pos))
 
 
 def mixing_p2p_ref(x: jax.Array, x_tilde: jax.Array, x_partner: jax.Array,
@@ -37,7 +86,7 @@ def mixing_gossip_stacked_ref(x: jax.Array, x_tilde: jax.Array,
                               ) -> tuple[jax.Array, jax.Array]:
     """Oracle for the worker-stacked fused batch: x, x~ are (W, D), partner
     (W,) an involution (partner[w]==w for idle workers), dt_next (W,)."""
-    xp = jnp.take(x, partner, axis=0)
+    xp = take_rows(x, partner)
     m = x - xp
     x1 = x - alpha * m
     xt1 = x_tilde - alpha_t * m
@@ -72,8 +121,7 @@ def mixing_gossip_worlds_ref(x: jax.Array, x_tilde: jax.Array,
     with the *2 in the exponent), and idle rows (partner[b, w] == w) stay
     exact no-ops.
     """
-    xp = jnp.take_along_axis(x, partner[:, :, None].astype(jnp.int32),
-                             axis=1)
+    xp = take_partner_rows(x, partner)
     m = x - xp
     x1 = x - _per_world(alpha, x) * m
     xt1 = x_tilde - _per_world(alpha_t, x) * m

@@ -27,7 +27,8 @@ def run_one(arch: str, shape_name: str, mesh_name: str,
             serve_param_mode: str = "fsdp",
             train_microbatches: int = 4,
             carry_shard: str = None) -> dict:
-    from repro.analysis.roofline import model_flops, roofline_terms
+    from repro.analysis.roofline import (V5E, device_peaks, model_flops,
+                                         roofline_terms)
     from repro.configs import get_config
     from repro.launch.mesh import make_gossip_mesh, make_production_mesh, rules_for
     from repro.launch.steps import bundle_for
@@ -83,7 +84,8 @@ def run_one(arch: str, shape_name: str, mesh_name: str,
         arch=arch, shape=shape_name, mesh_name=mesh_name,
         chips=mesh.devices.size,
         cost={"flops": hc.flops, "bytes accessed": hc.write_bytes},
-        hlo_text="", model_flops_total=mf, peak_memory=float(peak))
+        hlo_text="", model_flops_total=mf, peak_memory=float(peak),
+        peaks=device_peaks(V5E))
     report = dataclasses.replace(
         report, collective_bytes=float(hc.collective_bytes),
         collective_detail=hc.collective_detail)

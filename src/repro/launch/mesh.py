@@ -10,11 +10,6 @@ import jax
 
 from .. import sharding
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 50e9                 # B/s per link
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -44,7 +44,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.defense import (DefenseState, defense_absorb, defense_comm,
@@ -52,6 +52,7 @@ from ..core.defense import (DefenseState, defense_absorb, defense_comm,
 from ..core.engine import FlatGossipEngine
 from ..core.flatbuf import ring_pool_exchange
 from ..core.simulator import SimState, SimTrace, _jit_pair
+from ..kernels.a2cid2_mixing.ref import take_partner_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,7 +184,7 @@ def _sharded_scan(sim, state, pw, gammas, taus, dk, arrays, horizon, tel,
                 if horizon:
                     xp = engine.partner_values_worlds(ring, bx, lp, slot)
                 else:
-                    xp = jnp.take_along_axis(bx, lp[:, :, None], axis=1)
+                    xp = take_partner_rows(bx, lp)
                 # boundary publish -> permute-ring pool -> cross reads
                 pv = engine.publish_rows(ring, bx, prow[0], pslot[0])
                 pool = ring_pool_exchange(pv, ax, ns)
@@ -265,7 +266,7 @@ def _sharded_scan(sim, state, pw, gammas, taus, dk, arrays, horizon, tel,
         in_specs=(bank, bank, rep, row, xs_specs, rep, rep,
                   (rep,) * len(taus_t), (rep,) * len(dk_t)),
         out_specs=(bank, bank, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )(bx, bxt, state.key, prologue, xs, pw, gammas, taus_t, dk_t)
     final = SimState(engine.unpack_worlds(bx), engine.unpack_worlds(bxt),
                      t_final, key)

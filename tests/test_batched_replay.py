@@ -3,7 +3,9 @@
 The contracts under test:
 
   * pinning — ``Simulator.run_worlds`` replays every world of a batch
-    bit-for-bit identically to its serial per-world replay, per flavor
+    bit-for-bit identically to its serial per-world replay (within 2 ulp
+    of the bank's scale where XLA fuses the two programs differently; see
+    DESIGN.md §11), per flavor
     (engine vs per-event reference, plain vs channel), across ragged
     stream lengths, channel worlds with DISTINCT staleness horizons, and
     both kernel backends (jnp oracle + interpret-mode Pallas);
@@ -57,23 +59,35 @@ def _states(sim, count):
             for _ in range(count)]
 
 
-def _assert_world_pinned(sim, fin, tr, i, serial_fin, serial_tr):
-    """World i of a batched replay equals its serial replay bit-for-bit
-    (states AND per-round traces)."""
+def _assert_ulp_pinned(a, b, maxulp=0):
+    """``a`` equals ``b`` bitwise (maxulp 0), or within ``maxulp`` ulps of
+    ``b``'s largest magnitude.  The scale is the array's, not each
+    element's: a 1-ulp rounding difference upstream of a cancelling
+    subtraction is many ulps of the small result."""
+    a, b = np.asarray(a), np.asarray(b)
+    if maxulp == 0:
+        np.testing.assert_array_equal(a, b)
+        return
+    tol = maxulp * np.spacing(np.max(np.abs(b)).astype(b.dtype))
+    err = np.max(np.abs(a.astype(np.float64) - b.astype(np.float64)))
+    assert err <= tol, f"max |a - b| = {err} > {maxulp} ulp ({tol})"
+
+
+def _assert_world_pinned(sim, fin, tr, i, serial_fin, serial_tr, maxulp=0):
+    """World i of a batched replay equals its serial replay (states AND
+    per-round traces): bit-for-bit, or within ``maxulp`` ulps."""
     for bl, sl in zip(jax.tree.leaves(fin.x), jax.tree.leaves(serial_fin.x)):
-        np.testing.assert_array_equal(np.asarray(bl[i]), np.asarray(sl))
+        _assert_ulp_pinned(bl[i], sl, maxulp)
     for bl, sl in zip(jax.tree.leaves(fin.x_tilde),
                       jax.tree.leaves(serial_fin.x_tilde)):
-        np.testing.assert_array_equal(np.asarray(bl[i]), np.asarray(sl))
+        _assert_ulp_pinned(bl[i], sl, maxulp)
     np.testing.assert_array_equal(np.asarray(fin.t_last[i]),
                                   np.asarray(serial_fin.t_last))
-    np.testing.assert_array_equal(np.asarray(tr.loss[i]),
-                                  np.asarray(serial_tr.loss))
-    np.testing.assert_array_equal(np.asarray(tr.consensus[i]),
-                                  np.asarray(serial_tr.consensus))
+    _assert_ulp_pinned(tr.loss[i], serial_tr.loss, maxulp)
+    _assert_ulp_pinned(tr.consensus[i], serial_tr.consensus, maxulp)
 
 
-def _pin_batch(sim, worlds_params_seeds, engine):
+def _pin_batch(sim, worlds_params_seeds, engine, maxulp=0):
     """Run the batch through run_worlds and pin every world to its serial
     replay on the same flavor."""
     scheds = [w.compile(ROUNDS, seed=s) for w, _, s in worlds_params_seeds]
@@ -84,7 +98,7 @@ def _pin_batch(sim, worlds_params_seeds, engine):
     for i, (st, sch, p) in enumerate(zip(states, scheds, plist)):
         serial = dataclasses.replace(sim, params=p)
         sfin, str_ = serial.run_schedule(st, sch, engine=engine)
-        _assert_world_pinned(sim, fin, tr, i, sfin, str_)
+        _assert_world_pinned(sim, fin, tr, i, sfin, str_, maxulp)
 
 
 # ---------------------------------------------------------------- pinning
@@ -93,7 +107,14 @@ def _pin_batch(sim, worlds_params_seeds, engine):
 @pytest.mark.parametrize("engine", [True, False])
 def test_batched_equals_serial_ragged_mixed_params(backend, engine):
     """Ragged stream lengths (comms_per_grad grid + a different topology)
-    and mixed baseline/accelerated params, one batch, every world pinned."""
+    and mixed baseline/accelerated params, one batch, every world pinned.
+
+    The engine pin is ulp-level (within 2 ulp of the bank's scale), not
+    bitwise: XLA:CPU contracts multiply-adds into FMAs inside its fused
+    loops, and the serial program (static eta, alpha) and the batched one
+    (per-world arrays) fuse the gradient update differently.  With fusion
+    off, or on an ISA without FMA (``--xla_cpu_max_isa=SSE4_2``), the
+    two pin bitwise.  The per-event reference pins bitwise."""
     ring = ring_graph(N)
     comp = build_graph("complete", N)
     sim = _make_sim(backend=backend)
@@ -107,7 +128,7 @@ def test_batched_equals_serial_ragged_mixed_params(backend, engine):
         (World(topology=comp),
          params_from_graph(comp, True), 2),
     ]
-    _pin_batch(sim, batch, engine)
+    _pin_batch(sim, batch, engine, maxulp=2 if engine else 0)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -116,7 +137,11 @@ def test_batched_channel_distinct_horizons(backend, engine):
     """Channel worlds with DISTINCT delay horizons (plus a clean world and
     a Byzantine/drop world) share one batched channel replay; each pins to
     its serial replay — the shared ring depth H = max horizon serves every
-    world the same snapshots its own-depth serial ring would."""
+    world the same snapshots its own-depth serial ring would.
+
+    The engine pin is ulp-level (within 2 ulp of the bank's scale), for
+    the FMA-contraction reason given above; the per-event reference pins
+    bitwise."""
     ring = ring_graph(N)
     acid = params_from_graph(ring, True)
     base = params_from_graph(ring, False)
@@ -132,7 +157,7 @@ def test_batched_channel_distinct_horizons(backend, engine):
                                      prob=0.6),
             drop_prob=0.1)), acid, 3),
     ]
-    _pin_batch(sim, batch, engine)
+    _pin_batch(sim, batch, engine, maxulp=2 if engine else 0)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

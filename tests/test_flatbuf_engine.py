@@ -212,8 +212,7 @@ def test_stacked_trainer_zero_comms_is_noop():
 def test_run_schedule_handles_f64_state():
     """float64 state (x64 mode) worked on the per-event path; the engine
     default must keep working (the layout infers an f64 buffer)."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         n, d = 4, 8
         b = jax.random.normal(jax.random.PRNGKey(1), (n, d))
 

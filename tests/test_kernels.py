@@ -16,6 +16,33 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
 # ------------------------------------------------------------ a2cid2_mixing
 
+@pytest.mark.parametrize("wide", [False, True])
+def test_row_gathers_equal_advanced_indexing(monkeypatch, wide):
+    """The reference's row gathers give ``x[idx]`` bit for bit on both
+    sides of WIDE_ROW: XLA's gather below it, one dynamic slice per row
+    at or above it (the form the TPU compiler handles at model width)."""
+    from repro.kernels.a2cid2_mixing import ref
+    if wide:
+        monkeypatch.setattr(ref, "WIDE_ROW", 1)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    x = jax.random.normal(ks[0], (2, 4, 256))
+    pool = jax.random.normal(ks[1], (3, 2, 5, 256))
+    idx = jnp.asarray([2, 0, 3, 3], jnp.int32)
+    partner = jnp.asarray([[1, 0, 3, 2], [0, 1, 2, 3]], jnp.int32)
+    hop = jnp.asarray([[0, 2, 1], [1, 1, 0]], jnp.int32)
+    pos = jnp.asarray([[4, 0, 2], [3, 1, 1]], jnp.int32)
+    np.testing.assert_array_equal(np.asarray(ref.take_rows(x[0], idx)),
+                                  np.asarray(x[0])[np.asarray(idx)])
+    want = np.take_along_axis(np.asarray(x), np.asarray(partner)[:, :, None],
+                              axis=1)
+    np.testing.assert_array_equal(
+        np.asarray(ref.take_partner_rows(x, partner)), want)
+    want = np.asarray(pool)[np.asarray(hop), np.arange(2)[:, None],
+                            np.asarray(pos)]
+    np.testing.assert_array_equal(
+        np.asarray(ref.take_pool_rows(pool, hop, pos)), want)
+
+
 @pytest.mark.parametrize("n", [128, 4096, 70_000])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mixing_kernel_matches_oracle(n, dtype):

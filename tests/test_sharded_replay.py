@@ -82,22 +82,36 @@ def _mesh(n=None):
     return MeshReplay(make_replay_mesh(NS if n is None else n))
 
 
-def _assert_state_pinned(f0, f1):
+def _assert_ulp_pinned(a, b, maxulp=0):
+    """``a`` equals ``b`` bitwise (maxulp 0), or within ``maxulp`` ulps of
+    ``b``'s largest magnitude (a rounding difference upstream of a
+    cancelling subtraction is many ulps of the small result)."""
+    a, b = np.asarray(a), np.asarray(b)
+    if maxulp == 0:
+        np.testing.assert_array_equal(a, b)
+        return
+    tol = maxulp * np.spacing(np.max(np.abs(b)).astype(b.dtype))
+    err = np.max(np.abs(a.astype(np.float64) - b.astype(np.float64)))
+    assert err <= tol, f"max |a - b| = {err} > {maxulp} ulp ({tol})"
+
+
+def _assert_state_pinned(f0, f1, maxulp=0):
     for a, c in zip(jax.tree.leaves(f0.x), jax.tree.leaves(f1.x)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        _assert_ulp_pinned(a, c, maxulp)
     for a, c in zip(jax.tree.leaves(f0.x_tilde),
                     jax.tree.leaves(f1.x_tilde)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        _assert_ulp_pinned(a, c, maxulp)
 
 
-def _pin_mesh(sim, worlds, seeds, mr, **kw):
+def _pin_mesh(sim, worlds, seeds, mr, maxulp=0, **kw):
     """mesh= replay of a batch equals the single-device replay: states
-    bitwise, traces allclose (metrics psum across shards)."""
+    bitwise (or within ``maxulp`` ulps), traces allclose (metrics psum
+    across shards)."""
     scheds = [w.compile(ROUNDS, seed=s) for w, s in zip(worlds, seeds)]
     states = _states(sim, len(scheds))
     f0, t0 = sim.run_worlds(states, scheds, **kw)
     f1, t1 = sim.run_worlds(states, scheds, mesh=mr, **kw)
-    _assert_state_pinned(f0, f1)
+    _assert_state_pinned(f0, f1, maxulp)
     np.testing.assert_allclose(np.asarray(t0.loss), np.asarray(t1.loss),
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(t0.consensus),
@@ -159,12 +173,17 @@ def test_defense_worlds_pin():
 
 def test_single_shard_mesh_pins():
     """An n_shards=1 mesh (always constructible) runs the sharded twins
-    with an empty boundary and still pins bitwise — the degenerate case
-    every device count can exercise."""
+    with an empty boundary and still pins — the degenerate case every
+    device count can exercise.  The pin is ulp-level (within 2 ulp of the
+    bank's scale), not bitwise: XLA:CPU contracts multiply-adds into FMAs
+    inside fused loops, and the shard_map body and the single-device scan
+    fuse differently.  With fusion off, or ``--xla_cpu_max_isa=SSE4_2``,
+    the pin is bitwise (DESIGN.md §16)."""
     ring = ring_graph(N)
     sim = _make_sim()
     _pin_mesh(sim, [World(topology=ring, channel=ChannelModel(
-        delay=DelayProcess(horizon=2, prob=0.5)))], [2], _mesh(1))
+        delay=DelayProcess(horizon=2, prob=0.5)))], [2], _mesh(1),
+        maxulp=2)
 
 
 @multi
