@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -155,29 +156,28 @@ def _exec_cost(tag: str, jitted, *args) -> dict:
     """
     try:
         from repro.analysis import cost_from_hlo
-        t0 = _TRACER.now_us() if _TRACER is not None else 0.0
-        compiled = jitted.lower(*args).compile()
-        cost = cost_from_hlo(compiled.as_text())
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        row = {
-            "executable": tag, "method": "hlo",
-            "flops": cost.flops,
-            "write_bytes": cost.write_bytes,
-            "collective_bytes": cost.collective_bytes,
-            "collective_detail": cost.collective_detail,
-            "xla_flops": float(ca.get("flops", 0.0)),
-            "xla_bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-            **_roofline(cost.flops, cost.write_bytes,
-                        cost.collective_bytes),
-        }
-        if _TRACER is not None:
-            _TRACER.complete(
-                f"jit.compile.{tag}", t0, _TRACER.now_us() - t0,
-                lane="compile",
-                args={k: row[k] for k in ("flops", "write_bytes",
-                                          "collective_bytes")})
+        span_args = {}
+        with (_TRACER.span(f"jit.compile.{tag}", lane="compile",
+                           args=span_args)
+              if _TRACER is not None else nullcontext()):
+            compiled = jitted.lower(*args).compile()
+            cost = cost_from_hlo(compiled.as_text())
+            ca = compiled.cost_analysis() or {}
+            if isinstance(ca, (list, tuple)):
+                ca = ca[0] if ca else {}
+            row = {
+                "executable": tag, "method": "hlo",
+                "flops": cost.flops,
+                "write_bytes": cost.write_bytes,
+                "collective_bytes": cost.collective_bytes,
+                "collective_detail": cost.collective_detail,
+                "xla_flops": float(ca.get("flops", 0.0)),
+                "xla_bytes_accessed": float(ca.get("bytes accessed", 0.0)),
+                **_roofline(cost.flops, cost.write_bytes,
+                            cost.collective_bytes),
+            }
+            span_args.update({k: row[k] for k in ("flops", "write_bytes",
+                                                  "collective_bytes")})
         return row
     except Exception as e:
         if _on_tpu():
@@ -1119,19 +1119,18 @@ def bench_defense(seed: int = 0) -> list[str]:
     # SAME batched scan (one trace, one dispatch — asserted below)
     tel = Telemetry()
     before = Simulator._run_worlds_defense_jit._cache_size()
-    t_span = _TRACER.now_us() if _TRACER is not None else 0.0
-    t0 = time.perf_counter()
-    _, trace = sim.run_worlds(states, scheds, params=plist,
-                              robust_clips=clips, defenses=defs,
-                              telemetry=tel)
-    jax.block_until_ready(trace)
-    us_grid = (time.perf_counter() - t0) * 1e6
-    traces = Simulator._run_worlds_defense_jit._cache_size() - before
-    if _TRACER is not None:
-        _TRACER.complete("dispatch.defense_grid", t_span, us_grid,
-                         lane="dispatch",
-                         args={"worlds": len(worlds),
-                               "jit_traces": int(traces)})
+    span_args = {"worlds": len(worlds)}
+    with (_TRACER.span("dispatch.defense_grid", lane="dispatch",
+                       args=span_args)
+          if _TRACER is not None else nullcontext()):
+        t0 = time.perf_counter()
+        _, trace = sim.run_worlds(states, scheds, params=plist,
+                                  robust_clips=clips, defenses=defs,
+                                  telemetry=tel)
+        jax.block_until_ready(trace)
+        us_grid = (time.perf_counter() - t0) * 1e6
+        traces = Simulator._run_worlds_defense_jit._cache_size() - before
+        span_args["jit_traces"] = int(traces)
     cons = np.asarray(trace.consensus, np.float64)
     rejn = np.asarray(trace.defense.rejections, np.float64)
     quarn = np.asarray(trace.defense.quarantined, np.float64)

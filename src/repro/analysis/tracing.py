@@ -1,4 +1,20 @@
-"""Host-side span tracer: Chrome-trace-event JSON (DESIGN.md §15).
+"""Replay scopes, profiler spans, and the host-side span tracer
+(DESIGN.md §15).
+
+Three kinds of marks, one vocabulary:
+
+  * ``scope(name)`` — a ``jax.named_scope`` over one phase of the replay's
+    device work (``SCOPES``).  It is compile-time metadata: every HLO op
+    traced inside carries the name in its ``op_name``, so a device op in a
+    profile is put down to its phase through the compiled HLO text.  It
+    changes no arithmetic and is always on.
+  * ``span(name)`` — a host span on the profiler's clock
+    (``jax.profiler.TraceAnnotation``), the clock of the device ops.  With
+    no profiler active it costs one check.
+  * ``SpanTracer`` — Chrome-trace-event JSON of host spans for the fleet
+    and the CPU benchmarks; each of its spans also opens ``span(name)``, so
+    the two line up when a profile is taken.  ``gc_spans`` names Python's
+    collections the same way.
 
 The compiled side of the flight recorder (``core/telemetry.py``) records
 WHAT the replay did, per round, as data on the scan carry.  This module
@@ -24,13 +40,76 @@ the CI trace-smoke step.
 """
 from __future__ import annotations
 
+import dataclasses
+import gc
 import json
 import time
 from contextlib import contextmanager
 from typing import Any
 
+import jax
+
 # trace-event phases we emit (and validate_trace accepts)
 _PHASES = {"X", "C", "i", "M"}
+
+# phases of the engine replay's device work, one named scope each:
+# bank -> pytree, the model's forward and backward, gradients -> bank, the
+# SGD step on x and x~, the per-tick SimTrace reductions, the standalone
+# mixing sweeps, and the gossip groups with their partner reads
+SCOPES = ("replay.unpack", "replay.grad", "replay.pack", "replay.update",
+          "replay.record", "replay.mix", "replay.gossip")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of ``SCOPES``; a context manager
+    or a method decorator.  Raises on any other name."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown replay scope {name!r}; known: {SCOPES}")
+    return jax.named_scope(name)
+
+
+@contextmanager
+def span(name: str):
+    """A host span on the profiler's clock, ``jax.profiler.TraceAnnotation``
+    (a no-op check when no profile is being taken); a context manager or
+    a function decorator."""
+    with jax.profiler.TraceAnnotation(name):
+        yield
+
+
+@dataclasses.dataclass
+class GcStats:
+    """Python garbage collections seen inside one ``gc_spans`` block."""
+    collections: int = 0
+    seconds: float = 0.0
+    longest: float = 0.0
+
+
+@contextmanager
+def gc_spans():
+    """Open a ``gc`` span around every collection of Python's garbage
+    collector inside the block, and count them; yields the ``GcStats``."""
+    stats = GcStats()
+    running = []
+
+    def hook(phase, info):
+        if phase == "start":
+            ann = span("gc")
+            ann.__enter__()
+            running.append((ann, time.perf_counter()))
+        elif running:
+            ann, t0 = running.pop()
+            ann.__exit__(None, None, None)
+            dt = time.perf_counter() - t0
+            stats.collections += 1
+            stats.seconds += dt
+            stats.longest = max(stats.longest, dt)
+
+    gc.callbacks.append(hook)
+    try:
+        yield stats
+    finally:
+        gc.callbacks.remove(hook)
 
 
 class SpanTracer:
@@ -80,31 +159,21 @@ class SpanTracer:
     @contextmanager
     def span(self, name: str, *, process: str | None = None,
              lane: str = "main", args: dict | None = None):
-        """Context manager emitting one complete ("X") span.  ``process``
-        defaults to the tracer's root process (every emitter below
-        does)."""
+        """Context manager emitting one complete ("X") span, also opened
+        as a profiler span.  ``process`` defaults to the tracer's root
+        process.  ``args`` is read when the span closes, so the block may
+        fill in what it measured."""
         pid = self.process(process or self._root)
         tid = self.thread(pid, lane)
         t0 = self.now_us()
         try:
-            yield self
+            with span(name):
+                yield self
         finally:
             self.events.append({
                 "ph": "X", "name": name, "pid": pid, "tid": tid,
                 "ts": t0, "dur": self.now_us() - t0,
                 "args": _jsonable(args or {})})
-
-    def complete(self, name: str, ts_us: float, dur_us: float, *,
-                 process: str | None = None, lane: str = "main",
-                 args: dict | None = None) -> None:
-        """An explicit-timestamp "X" span (for durations measured
-        elsewhere, e.g. ``_timeit`` results)."""
-        pid = self.process(process or self._root)
-        tid = self.thread(pid, lane)
-        self.events.append({"ph": "X", "name": name, "pid": pid,
-                            "tid": tid, "ts": float(ts_us),
-                            "dur": float(dur_us),
-                            "args": _jsonable(args or {})})
 
     def instant(self, name: str, *, process: str | None = None,
                 lane: str = "main", args: dict | None = None) -> None:

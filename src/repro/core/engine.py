@@ -31,6 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..analysis.tracing import scope
 from ..kernels.a2cid2_mixing.ops import (channel_event_local,
                                          channel_event_stacked,
                                          channel_event_worlds,
@@ -87,6 +88,10 @@ class FlatGossipEngine:
 
     The norm rules cost one extra fused reduce over (x, xp) to derive the
     per-worker scale; the kernel itself stays 3 reads + 2 writes.
+
+    Each pass runs under a replay scope (``analysis.tracing.SCOPES``):
+    the mixing sweeps under ``replay.mix``, the gossip groups, partner
+    reads, ring passes and delta norms under ``replay.gossip``.
     """
 
     layout: FlatLayout
@@ -130,11 +135,13 @@ class FlatGossipEngine:
         return self.layout.unpack_worlds(buf)
 
     # -------------------------------------------------------------- passes
+    @scope("replay.mix")
     def mix(self, bx: jax.Array, bxt: jax.Array, dt) -> tuple[jax.Array,
                                                               jax.Array]:
         """Standalone mixing sweep (engine prologue; 2 reads + 2 writes)."""
         return mix_flat(bx, bxt, self.params.eta, dt)
 
+    @scope("replay.gossip")
     def batch(self, bx: jax.Array, bxt: jax.Array, partner: jax.Array,
               dt_next: jax.Array) -> tuple[jax.Array, jax.Array]:
         """One fused group [p2p(partner), mix(dt_next)] on (W, D) buffers."""
@@ -143,6 +150,7 @@ class FlatGossipEngine:
                                     alpha=p.alpha, alpha_t=p.alpha_tilde,
                                     backend=self.backend)
 
+    @scope("replay.gossip")
     def batch_local(self, bx: jax.Array, bxt: jax.Array, xp: jax.Array,
                     dt_next) -> tuple[jax.Array, jax.Array]:
         """One fused group on per-worker (D,) vectors (SPMD path); ``xp`` is
@@ -157,11 +165,13 @@ class FlatGossipEngine:
     # (eta, alpha, alpha_t)`` passed dynamically, so one trace serves a
     # whole sweep family (baseline + accelerated + every grid point).
 
+    @scope("replay.mix")
     def mix_batch(self, bx: jax.Array, bxt: jax.Array, dt, eta: jax.Array
                   ) -> tuple[jax.Array, jax.Array]:
         """World-batched standalone mixing sweep (batched prologue)."""
         return mix_worlds(bx, bxt, eta, dt)
 
+    @scope("replay.gossip")
     def batch_worlds(self, bx: jax.Array, bxt: jax.Array,
                      partner: jax.Array, dt_next: jax.Array, pw
                      ) -> tuple[jax.Array, jax.Array]:
@@ -171,6 +181,7 @@ class FlatGossipEngine:
         return gossip_event_worlds(bx, bxt, partner, dt_next, eta, alpha,
                                    alpha_t, backend=self.backend)
 
+    @scope("replay.gossip")
     def channel_batch_worlds(self, bx: jax.Array, bxt: jax.Array,
                              xp: jax.Array, corrupt: jax.Array,
                              dt_next: jax.Array, pw, taus=None
@@ -188,6 +199,7 @@ class FlatGossipEngine:
                                     clip=self._coord_clip(),
                                     backend=self.backend)
 
+    @scope("replay.gossip")
     def channel_batch_worlds_scaled(self, bx: jax.Array, bxt: jax.Array,
                                     xp: jax.Array, corrupt: jax.Array,
                                     mscale: jax.Array, dt_next: jax.Array,
@@ -202,15 +214,18 @@ class FlatGossipEngine:
                                     eta, alpha, alpha_t, clip=None,
                                     want_rej=True, backend=self.backend)
 
+    @scope("replay.gossip")
     def ring_init_worlds(self, bx: jax.Array, horizon: int) -> jax.Array:
         """(B, H, W, D) per-world snapshot rings seeded with ``bx``."""
         return ring_init_worlds(bx, horizon)
 
+    @scope("replay.gossip")
     def ring_push_worlds(self, ring: jax.Array, bx: jax.Array, pos
                          ) -> jax.Array:
         """Rotate every world's ring at the (shared) slot ``pos``."""
         return ring_push_worlds(ring, bx, pos)
 
+    @scope("replay.gossip")
     def partner_values_worlds(self, ring: jax.Array, bx: jax.Array,
                               partner: jax.Array, src_slot: jax.Array
                               ) -> jax.Array:
@@ -237,6 +252,7 @@ class FlatGossipEngine:
         return jnp.minimum(1.0, tau / jnp.maximum(nrm, 1e-30)
                            ).astype(jnp.float32)
 
+    @scope("replay.gossip")
     def delta_norms(self, bx: jax.Array, xp: jax.Array, corrupt: jax.Array,
                     axes) -> jax.Array:
         """f32 L2 norms of the corrupted channel deltas — one fused reduce
@@ -260,6 +276,7 @@ class FlatGossipEngine:
         return self._norm_scale(self.delta_norms(bx, xp, corrupt, axes),
                                 taus=taus)
 
+    @scope("replay.gossip")
     def channel_batch(self, bx: jax.Array, bxt: jax.Array, xp: jax.Array,
                       corrupt: jax.Array, dt_next: jax.Array
                       ) -> tuple[jax.Array, jax.Array]:
@@ -277,6 +294,7 @@ class FlatGossipEngine:
                                      clip=self._coord_clip(),
                                      backend=self.backend)
 
+    @scope("replay.gossip")
     def channel_batch_scaled(self, bx: jax.Array, bxt: jax.Array,
                              xp: jax.Array, corrupt: jax.Array,
                              mscale: jax.Array, dt_next: jax.Array
@@ -291,6 +309,7 @@ class FlatGossipEngine:
                                      alpha_t=p.alpha_tilde, clip=None,
                                      want_rej=True, backend=self.backend)
 
+    @scope("replay.gossip")
     def channel_batch_local(self, bx: jax.Array, bxt: jax.Array,
                             xp: jax.Array, corrupt, dt_next
                             ) -> tuple[jax.Array, jax.Array]:
@@ -306,14 +325,17 @@ class FlatGossipEngine:
                                    backend=self.backend)
 
     # --------------------------------------------------- snapshot ring API
+    @scope("replay.gossip")
     def ring_init(self, bx: jax.Array, horizon: int) -> jax.Array:
         """(H, W, D) snapshot ring seeded with the current buffer."""
         return ring_init(bx, horizon)
 
+    @scope("replay.gossip")
     def ring_push(self, ring: jax.Array, bx: jax.Array, pos) -> jax.Array:
         """Rotate: store the post-gradient state at slot ``pos`` (r mod H)."""
         return ring_push(ring, bx, pos)
 
+    @scope("replay.gossip")
     def partner_values(self, ring: jax.Array, bx: jax.Array,
                        partner: jax.Array, src_slot: jax.Array) -> jax.Array:
         """Resolve per-worker partner reads: fresh rows of ``bx`` where
@@ -322,6 +344,7 @@ class FlatGossipEngine:
 
 
     # ------------------------------- sharded-replay passes (DESIGN.md §16)
+    @scope("replay.gossip")
     def publish_rows(self, ring, bx: jax.Array, rows: jax.Array,
                      slots: jax.Array) -> jax.Array:
         """Resolve the (B, nb) boundary rows a shard publishes into their
@@ -339,6 +362,7 @@ class FlatGossipEngine:
         stale = ring[b_idx, clamped, rows]
         return jnp.where((slots < h)[:, :, None], stale, fresh)
 
+    @scope("replay.gossip")
     def pool_partner_values(self, pool: jax.Array, hop: jax.Array,
                             pos: jax.Array, xp_local: jax.Array,
                             is_cross: jax.Array) -> jax.Array:

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis.tracing import scope
 from ..kernels.a2cid2_mixing.ref import take_rows
 
 PyTree = Any
@@ -123,6 +124,7 @@ class FlatLayout:
                    buf_dtype=buf_dtype)
 
     # ---------------------------------------------------------------- pack
+    @scope("replay.pack")
     def pack(self, tree: PyTree) -> jax.Array:
         """Stacked pytree (leaves (W, *shape)) -> (W, D) buffer."""
         leaves = self.treedef.flatten_up_to(tree)
@@ -133,6 +135,7 @@ class FlatLayout:
             cols.append(jnp.zeros((w, self.d - self.d_real), self.buf_dtype))
         return jnp.concatenate(cols, axis=1)
 
+    @scope("replay.unpack")
     def unpack(self, buf: jax.Array) -> PyTree:
         """(W, D) buffer -> stacked pytree with original shapes/dtypes."""
         w = buf.shape[0]
@@ -143,6 +146,7 @@ class FlatLayout:
         ]
         return self.treedef.unflatten(leaves)
 
+    @scope("replay.pack")
     def pack_local(self, tree: PyTree) -> jax.Array:
         """Replica pytree (leaves (*shape)) -> (D,) vector."""
         leaves = self.treedef.flatten_up_to(tree)
@@ -152,6 +156,7 @@ class FlatLayout:
             cols.append(jnp.zeros((self.d - self.d_real,), self.buf_dtype))
         return jnp.concatenate(cols, axis=0)
 
+    @scope("replay.unpack")
     def unpack_local(self, vec: jax.Array) -> PyTree:
         """(D,) vector -> replica pytree with original shapes/dtypes."""
         leaves = [
@@ -160,6 +165,7 @@ class FlatLayout:
         ]
         return self.treedef.unflatten(leaves)
 
+    @scope("replay.pack")
     def pack_worlds(self, tree: PyTree) -> jax.Array:
         """World-batched pytree (leaves (B, W, *shape)) -> (B, W, D)."""
         leaves = self.treedef.flatten_up_to(tree)
@@ -171,6 +177,7 @@ class FlatLayout:
                                   self.buf_dtype))
         return jnp.concatenate(cols, axis=2)
 
+    @scope("replay.unpack")
     def unpack_worlds(self, buf: jax.Array) -> PyTree:
         """(B, W, D) buffer -> world-batched pytree."""
         b, w = buf.shape[:2]

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis.tracing import scope, span
 from .a2cid2 import (A2CiD2Params, apply_mixing, consensus_distance,
                      matched_p2p_update, worker_mean)
 from .channel import CORRUPT_KEY, STALE_KEY
@@ -248,6 +249,30 @@ class Simulator:
         return new_state, metrics
 
     # ------------------------------------------ coalesced flat-buffer steps
+    def _grad_tick(self, engine: FlatGossipEngine, n: int, bx, bxt, key,
+                   gscale):
+        """Shared gradient tick of the serial engine flavors: vmapped
+        grad_fn on the unpacked bank, the masked SGD step on both banks,
+        and the tick's SimTrace row (loss, consensus, mean norm)."""
+        with scope("replay.grad"):
+            key, sub = jax.random.split(key)
+            keys = jax.random.split(sub, n)
+            losses, grads = jax.vmap(self.grad_fn)(engine.unpack(bx), keys,
+                                                   jnp.arange(n))
+        g = engine.pack(grads)
+        with scope("replay.update"):
+            # grad_scale masks straggler/churned ticks (1.0 elsewhere)
+            g = gscale[:, None].astype(g.dtype) * g
+            bx = bx - self.gamma * g
+            bxt = bxt - self.gamma * g
+        with scope("replay.record"):
+            mean = jnp.mean(bx, axis=0, keepdims=True)
+            # padding columns are zero across workers: they add 0 to both
+            loss = jnp.mean(losses).astype(jnp.float32)
+            consensus = (jnp.sum((bx - mean) ** 2) / n).astype(jnp.float32)
+            mean_norm = jnp.sum(mean ** 2).astype(jnp.float32)
+        return bx, bxt, key, (loss, consensus, mean_norm)
+
     def _engine_step(self, engine: FlatGossipEngine, n: int, carry, xs):
         """One event-stream step: a fused comm batch OR a gradient tick,
         each followed by the precomputed mixing segment to the next step."""
@@ -261,22 +286,10 @@ class Simulator:
 
         def grad(args):
             bx, bxt, key = args
-            key, sub = jax.random.split(key)
-            keys = jax.random.split(sub, n)
-            losses, grads = jax.vmap(self.grad_fn)(engine.unpack(bx), keys,
-                                                   jnp.arange(n))
-            g = engine.pack(grads)
-            # grad_scale masks straggler/churned ticks (1.0 elsewhere)
-            g = gscale[:, None].astype(g.dtype) * g
-            bx = bx - self.gamma * g
-            bxt = bxt - self.gamma * g
-            mean = jnp.mean(bx, axis=0, keepdims=True)
-            # padding columns are zero across workers: they add 0 to both
-            loss = jnp.mean(losses).astype(jnp.float32)
-            consensus = (jnp.sum((bx - mean) ** 2) / n).astype(jnp.float32)
-            mean_norm = jnp.sum(mean ** 2).astype(jnp.float32)
+            bx, bxt, key, metrics = self._grad_tick(engine, n, bx, bxt, key,
+                                                    gscale)
             bx, bxt = engine.mix(bx, bxt, dt_nxt)
-            return (bx, bxt, key), (loss, consensus, mean_norm)
+            return (bx, bxt, key), metrics
 
         return jax.lax.cond(is_grad, grad, comm, carry)
 
@@ -532,12 +545,14 @@ class Simulator:
             if horizon:
                 xp = engine.partner_values(ring, bx, partner, src_slot)
             else:
-                xp = jnp.take(bx, partner, axis=0)
+                with scope("replay.gossip"):
+                    xp = jnp.take(bx, partner, axis=0)
             if tel is not None:
                 nrm = engine.delta_norms(bx, xp, corrupt, axes=1)
-                involved = partner != jnp.arange(n)
-                acc = self._tel_step(acc, involved, self._tel_rej(nrm),
-                                     nrm)
+                with scope("replay.record"):
+                    involved = partner != jnp.arange(n)
+                    acc = self._tel_step(acc, involved, self._tel_rej(nrm),
+                                         nrm)
             bx, bxt = engine.channel_batch(bx, bxt, xp, corrupt, dt_nxt)
             z = jnp.zeros((), jnp.float32)
             if tel is None:
@@ -549,25 +564,14 @@ class Simulator:
                 bx, bxt, ring, key = args
             else:
                 bx, bxt, ring, key, acc = args
-            key, sub = jax.random.split(key)
-            keys = jax.random.split(sub, n)
-            losses, grads = jax.vmap(self.grad_fn)(engine.unpack(bx), keys,
-                                                   jnp.arange(n))
-            g = engine.pack(grads)
-            g = gscale[:, None].astype(g.dtype) * g
-            bx = bx - self.gamma * g
-            bxt = bxt - self.gamma * g
-            mean = jnp.mean(bx, axis=0, keepdims=True)
-            loss = jnp.mean(losses).astype(jnp.float32)
-            consensus = (jnp.sum((bx - mean) ** 2) / n).astype(jnp.float32)
-            mean_norm = jnp.sum(mean ** 2).astype(jnp.float32)
+            bx, bxt, key, metrics = self._grad_tick(engine, n, bx, bxt, key,
+                                                    gscale)
             if horizon:
                 ring = engine.ring_push(ring, bx, ring_pos)
             bx, bxt = engine.mix(bx, bxt, dt_nxt)
             if tel is None:
-                return (bx, bxt, ring, key), (loss, consensus, mean_norm)
-            return (bx, bxt, ring, key, self._tel_zeros()), \
-                (loss, consensus, mean_norm) + acc
+                return (bx, bxt, ring, key), metrics
+            return (bx, bxt, ring, key, self._tel_zeros()), metrics + acc
 
         return jax.lax.cond(is_grad, grad, comm, carry)
 
@@ -630,17 +634,22 @@ class Simulator:
             if horizon:
                 xp = engine.partner_values(ring, bx, partner, src_slot)
             else:
-                xp = jnp.take(bx, partner, axis=0)
+                with scope("replay.gossip"):
+                    xp = jnp.take(bx, partner, axis=0)
             nrm = engine.delta_norms(bx, xp, corrupt, axes=1)
             involved = partner != jnp.arange(n)
-            mscale, quar, ds = defense_comm(dk, ds, partner, involved, nrm)
+            with scope("replay.gossip"):
+                mscale, quar, ds = defense_comm(dk, ds, partner, involved,
+                                                nrm)
             bx, bxt, rej = engine.channel_batch_scaled(bx, bxt, xp, corrupt,
                                                        mscale, dt_nxt)
-            ds = defense_absorb(ds, rej, quar, involved)
+            with scope("replay.gossip"):
+                ds = defense_absorb(ds, rej, quar, involved)
             z = jnp.zeros((), jnp.float32)
             if tel is None:
                 return (bx, bxt, ring, key, ds), (z, z, z, z, z, z)
-            acc = self._tel_step(acc, involved, rej, nrm)
+            with scope("replay.record"):
+                acc = self._tel_step(acc, involved, rej, nrm)
             return (bx, bxt, ring, key, ds, acc), (z,) * 10
 
         def grad(args):
@@ -648,28 +657,17 @@ class Simulator:
                 bx, bxt, ring, key, ds = args
             else:
                 bx, bxt, ring, key, ds, acc = args
-            key, sub = jax.random.split(key)
-            keys = jax.random.split(sub, n)
-            losses, grads = jax.vmap(self.grad_fn)(engine.unpack(bx), keys,
-                                                   jnp.arange(n))
-            g = engine.pack(grads)
-            g = gscale[:, None].astype(g.dtype) * g
-            bx = bx - self.gamma * g
-            bxt = bxt - self.gamma * g
-            mean = jnp.mean(bx, axis=0, keepdims=True)
-            loss = jnp.mean(losses).astype(jnp.float32)
-            consensus = (jnp.sum((bx - mean) ** 2) / n).astype(jnp.float32)
-            mean_norm = jnp.sum(mean ** 2).astype(jnp.float32)
-            ds, (tau, rejn, quarn) = defense_grad(dk, ds)
+            bx, bxt, key, metrics = self._grad_tick(engine, n, bx, bxt, key,
+                                                    gscale)
+            with scope("replay.gossip"):
+                ds, dtrace = defense_grad(dk, ds)
             if horizon:
                 ring = engine.ring_push(ring, bx, ring_pos)
             bx, bxt = engine.mix(bx, bxt, dt_nxt)
             if tel is None:
-                return (bx, bxt, ring, key, ds), (loss, consensus,
-                                                  mean_norm, tau, rejn,
-                                                  quarn)
+                return (bx, bxt, ring, key, ds), metrics + dtrace
             return (bx, bxt, ring, key, ds, self._tel_zeros()), \
-                (loss, consensus, mean_norm, tau, rejn, quarn) + acc
+                metrics + dtrace + acc
 
         return jax.lax.cond(is_grad, grad, comm, carry)
 
@@ -722,6 +720,7 @@ class Simulator:
         horizon = int(stale.max()) if stale.size else 0
         return stale, corrupt, horizon
 
+    @span("replay.stream")
     def channel_coalesced_arrays(self, state: SimState, sched: Schedule, *,
                                  cs=None):
         """Engine scan inputs for a channel schedule + the ring depth H.
@@ -782,7 +781,8 @@ class Simulator:
         """Per-event reference replay (unfused, sweeps masked slots too)."""
         fn = self._run_reference_dnt if self.donate \
             else self._run_reference_jit
-        return fn(state, schedule_arrays)
+        with span("replay.dispatch"):
+            return fn(state, schedule_arrays)
 
     def _run_coalesced_impl(self, state: SimState, stream_arrays
                             ) -> tuple[SimState, SimTrace]:
@@ -806,6 +806,7 @@ class Simulator:
 
     _run_coalesced_jit, _run_coalesced_dnt = _jit_pair(_run_coalesced_impl)
 
+    @span("replay.stream")
     def coalesced_arrays(self, state: SimState, sched: Schedule, *, cs=None):
         """Compile a schedule + start clocks into the engine's scan inputs.
 
@@ -832,7 +833,8 @@ class Simulator:
         """Flat-buffer engine replay of a coalesced event stream (hot path)."""
         fn = self._run_coalesced_dnt if self.donate \
             else self._run_coalesced_jit
-        return fn(state, stream_arrays)
+        with span("replay.dispatch"):
+            return fn(state, stream_arrays)
 
     def schedule_executable(self, state: SimState, sched: Schedule):
         """The exact (jitted replay, argument tuple) ``run_schedule``
@@ -915,12 +917,12 @@ class Simulator:
                 dk = knobs_single(defense, self.robust_clip)
                 fn = self._run_defense_dnt if self.donate \
                     else self._run_defense_jit
-                out = fn(state, dk, arrays, horizon, tel)
+                args = (state, dk, arrays, horizon, tel)
             elif channel:
                 arrays, horizon = self.channel_coalesced_arrays(state, sched)
                 fn = self._run_channel_dnt if self.donate \
                     else self._run_channel_jit
-                out = fn(state, arrays, horizon, tel)
+                args = (state, arrays, horizon, tel)
             else:
                 return self.run_coalesced(state,
                                           self.coalesced_arrays(state,
@@ -930,14 +932,16 @@ class Simulator:
             dk = knobs_single(defense, self.robust_clip)
             fn = self._run_defense_reference_dnt if self.donate \
                 else self._run_defense_reference_jit
-            out = fn(state, dk, arrays, horizon, tel)
+            args = (state, dk, arrays, horizon, tel)
         elif channel:
             arrays, horizon = self.channel_reference_arrays(sched)
             fn = self._run_channel_reference_dnt if self.donate \
                 else self._run_channel_reference_jit
-            out = fn(state, arrays, horizon, tel)
+            args = (state, arrays, horizon, tel)
         else:
             return self.run(state, self.reference_arrays(sched))
+        with span("replay.dispatch"):
+            out = fn(*args)
         if tel is None:
             return out
         final, tr = out
@@ -995,22 +999,25 @@ class Simulator:
         step-size array (built at default precision, so the cast to the
         buffer dtype reproduces the serial weak-scalar multiply
         bitwise)."""
-        ks = jax.vmap(jax.random.split)(key)
-        key, sub = ks[:, 0], ks[:, 1]
-        wkeys = jax.vmap(lambda k: jax.random.split(k, n))(sub)
-        losses, grads = jax.vmap(jax.vmap(self.grad_fn),
-                                 in_axes=(0, 0, None))(
-            engine.unpack_worlds(bx), wkeys, jnp.arange(n))
+        with scope("replay.grad"):
+            ks = jax.vmap(jax.random.split)(key)
+            key, sub = ks[:, 0], ks[:, 1]
+            wkeys = jax.vmap(lambda k: jax.random.split(k, n))(sub)
+            losses, grads = jax.vmap(jax.vmap(self.grad_fn),
+                                     in_axes=(0, 0, None))(
+                engine.unpack_worlds(bx), wkeys, jnp.arange(n))
         g = engine.pack_worlds(grads)
-        g = gscale[:, :, None].astype(g.dtype) * g
-        gs = jnp.asarray(gammas).astype(g.dtype)[:, None, None]
-        bx = bx - gs * g
-        bxt = bxt - gs * g
-        mean = jnp.mean(bx, axis=1, keepdims=True)
-        loss = jnp.mean(losses, axis=1).astype(jnp.float32)
-        consensus = (jnp.sum((bx - mean) ** 2, axis=(1, 2)) / n
-                     ).astype(jnp.float32)
-        mean_norm = jnp.sum(mean ** 2, axis=(1, 2)).astype(jnp.float32)
+        with scope("replay.update"):
+            g = gscale[:, :, None].astype(g.dtype) * g
+            gs = jnp.asarray(gammas).astype(g.dtype)[:, None, None]
+            bx = bx - gs * g
+            bxt = bxt - gs * g
+        with scope("replay.record"):
+            mean = jnp.mean(bx, axis=1, keepdims=True)
+            loss = jnp.mean(losses, axis=1).astype(jnp.float32)
+            consensus = (jnp.sum((bx - mean) ** 2, axis=(1, 2)) / n
+                         ).astype(jnp.float32)
+            mean_norm = jnp.sum(mean ** 2, axis=(1, 2)).astype(jnp.float32)
         return bx, bxt, key, (loss, consensus, mean_norm)
 
     def _worlds_step(self, engine: FlatGossipEngine, n: int, pw, gammas,
@@ -1076,13 +1083,15 @@ class Simulator:
                 xp = engine.partner_values_worlds(ring, bx, partner,
                                                   src_slot)
             else:
-                xp = jnp.take_along_axis(bx, partner[:, :, None], axis=1)
+                with scope("replay.gossip"):
+                    xp = jnp.take_along_axis(bx, partner[:, :, None], axis=1)
             if tel is not None:
                 nrm = engine.delta_norms(bx, xp, corrupt, axes=2)
-                involved = partner != jnp.arange(n)[None, :]
-                acc = self._tel_step(acc, involved,
-                                     self._tel_rej(nrm, taus), nrm,
-                                     batched=True)
+                with scope("replay.record"):
+                    involved = partner != jnp.arange(n)[None, :]
+                    acc = self._tel_step(acc, involved,
+                                         self._tel_rej(nrm, taus), nrm,
+                                         batched=True)
             bx, bxt = engine.channel_batch_worlds(bx, bxt, xp, corrupt,
                                                   dt_nxt, pw, taus)
             z = jnp.zeros((partner.shape[0],), jnp.float32)
@@ -1162,18 +1171,22 @@ class Simulator:
                 xp = engine.partner_values_worlds(ring, bx, partner,
                                                   src_slot)
             else:
-                xp = jnp.take_along_axis(bx, partner[:, :, None], axis=1)
+                with scope("replay.gossip"):
+                    xp = jnp.take_along_axis(bx, partner[:, :, None], axis=1)
             nrm = engine.delta_norms(bx, xp, corrupt, axes=2)
             involved = partner != jnp.arange(n)[None, :]
-            mscale, quar, ds = jax.vmap(defense_comm)(dk, ds, partner,
-                                                      involved, nrm)
+            with scope("replay.gossip"):
+                mscale, quar, ds = jax.vmap(defense_comm)(dk, ds, partner,
+                                                          involved, nrm)
             bx, bxt, rej = engine.channel_batch_worlds_scaled(
                 bx, bxt, xp, corrupt, mscale, dt_nxt, pw)
-            ds = jax.vmap(defense_absorb)(ds, rej, quar, involved)
+            with scope("replay.gossip"):
+                ds = jax.vmap(defense_absorb)(ds, rej, quar, involved)
             z = jnp.zeros((partner.shape[0],), jnp.float32)
             if tel is None:
                 return (bx, bxt, ring, key, ds), (z, z, z, z, z, z)
-            acc = self._tel_step(acc, involved, rej, nrm, batched=True)
+            with scope("replay.record"):
+                acc = self._tel_step(acc, involved, rej, nrm, batched=True)
             return (bx, bxt, ring, key, ds, acc), (z,) * 10
 
         def grad(args):
@@ -1183,7 +1196,8 @@ class Simulator:
                 bx, bxt, ring, key, ds, acc = args
             bx, bxt, key, metrics = self._grad_worlds(engine, n, bx, bxt,
                                                       key, gscale, gammas)
-            ds, (tau, rejn, quarn) = jax.vmap(defense_grad)(dk, ds)
+            with scope("replay.gossip"):
+                ds, (tau, rejn, quarn) = jax.vmap(defense_grad)(dk, ds)
             if horizon:
                 ring = engine.ring_push_worlds(ring, bx, ring_pos)
             bx, bxt = engine.mix_batch(bx, bxt, dt_nxt, pw[0])
@@ -1626,6 +1640,7 @@ class Simulator:
                 cache[id(s)] = coalesce_schedule(s)
         return [cache[id(s)] for s in scheds]
 
+    @span("replay.stream")
     def worlds_coalesced_arrays(self, states: SimState, scheds, *,
                                 css=None):
         """Engine scan inputs for B schedules: coalesce each world, align
@@ -1638,6 +1653,7 @@ class Simulator:
                 jnp.asarray(bs.grad_scale), jnp.asarray(bs.grad_pos),
                 jnp.asarray(bs.t_final))
 
+    @span("replay.stream")
     def worlds_channel_arrays(self, states: SimState, scheds, *, css=None):
         """Channel twin of ``worlds_coalesced_arrays`` + shared ring depth
         H = the max staleness ANY world demands (worlds with a shallower —
@@ -1749,7 +1765,8 @@ class Simulator:
             robust_clips=robust_clips, defenses=defenses, worlds=worlds,
             engine=engine, telemetry=telemetry, mesh=mesh)
         fn = self._twin_fn(twin, self.donate)
-        out = fn(*args)
+        with span("replay.dispatch"):
+            out = fn(*args)
         if tel is None:
             return out
         final, tr = out

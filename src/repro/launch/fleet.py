@@ -347,46 +347,45 @@ class GossipFleet:
             return True
 
         for r in range(R):
-            t_round = tracer.now_us() if tracer is not None else 0.0
             al = alive[r]
-            # churn: evict the newly-dead replicas' work to survivors
-            evicted: list[Request] = []
-            for w in range(self.n):
-                if prev_alive[w] and not al[w]:
-                    evicted.extend(scheds[w].evict_all())
-                    debt[w] = 0.0
-                    if tracer is not None:
-                        tracer.instant("churn.kill", process="fleet",
-                                       lane="churn",
-                                       args={"worker": w, "round": r})
-            # arrivals of round r, then re-admissions (and anything parked
-            # while the whole fleet was down)
-            arrivals = []
-            while cursor < len(requests) \
-                    and requests[cursor].arrive_round <= r:
-                arrivals.append(requests[cursor])
-                cursor += 1
-            parked, unrouted = unrouted, []
-            self._route(scheds, al, arrivals + evicted + parked, unrouted)
+            rargs = {"round": r, "alive": int(al.sum())}
+            with (tracer.span("fleet.round", process="fleet",
+                              lane="rounds", args=rargs)
+                  if tracer is not None else nullcontext()):
+                # churn: evict the newly-dead replicas' work to survivors
+                evicted: list[Request] = []
+                for w in range(self.n):
+                    if prev_alive[w] and not al[w]:
+                        evicted.extend(scheds[w].evict_all())
+                        debt[w] = 0.0
+                        if tracer is not None:
+                            tracer.instant("churn.kill", process="fleet",
+                                           lane="churn",
+                                           args={"worker": w, "round": r})
+                # arrivals of round r, then re-admissions (and anything
+                # parked while the whole fleet was down)
+                arrivals = []
+                while cursor < len(requests) \
+                        and requests[cursor].arrive_round <= r:
+                    arrivals.append(requests[cursor])
+                    cursor += 1
+                parked, unrouted = unrouted, []
+                self._route(scheds, al, arrivals + evicted + parked, unrouted)
 
-            # gossip events + drift tick of round r on the flat bank
-            carry, mets = round_fn(carry, tuple(a[r] for a in arrays))
-            consensus.append(mets["consensus"])
+                # gossip events + drift tick of round r on the flat bank
+                carry, mets = round_fn(carry, tuple(a[r] for a in arrays))
+                consensus.append(mets["consensus"])
 
-            # decode: alive replicas that aren't paying communication debt
-            debt[al] += self.stall_per_event * events[r][al]
-            decode_mask = al & (debt < 1.0)
-            stalled = al & ~decode_mask
-            debt[stalled] -= 1.0
-            stall_skips += int(stalled.sum())
-            decode_round(decode_mask, r)
-            prev_alive = al
+                # decode: alive replicas not paying communication debt
+                debt[al] += self.stall_per_event * events[r][al]
+                decode_mask = al & (debt < 1.0)
+                stalled = al & ~decode_mask
+                debt[stalled] -= 1.0
+                stall_skips += int(stalled.sum())
+                decode_round(decode_mask, r)
+                prev_alive = al
+                rargs["stalled"] = int(stalled.sum())
             if tracer is not None:
-                tracer.complete(
-                    "fleet.round", t_round, tracer.now_us() - t_round,
-                    process="fleet", lane="rounds",
-                    args={"round": r, "alive": int(al.sum()),
-                          "stalled": int(stalled.sum())})
                 tracer.counter(
                     "fleet.queue",
                     {"queue_depth": sum(len(scheds[w].queue)
@@ -404,22 +403,23 @@ class GossipFleet:
         # slot is empty (aliveness frozen at the last scheduled round)
         drain = 0
         al = alive[-1] if R else np.ones(self.n, bool)
-        t_drain = tracer.now_us() if tracer is not None else 0.0
-        while drain < max_drain_rounds:
-            if not unrouted and not any(
-                    scheds[w].pending() for w in range(self.n) if al[w]):
-                break
-            if not al.any():
-                break  # nobody alive: parked requests are unrecoverable
-            parked, unrouted = unrouted, []
-            self._route(scheds, al, parked, unrouted)
-            if not decode_round(al, R + drain) and not unrouted:
-                break
-            drain += 1
-        if tracer is not None:
-            tracer.complete("fleet.drain", t_drain,
-                            tracer.now_us() - t_drain, process="fleet",
-                            lane="rounds", args={"drain_rounds": drain})
+        dargs = {}
+        with (tracer.span("fleet.drain", process="fleet", lane="rounds",
+                          args=dargs)
+              if tracer is not None else nullcontext()):
+            while drain < max_drain_rounds:
+                if not unrouted and not any(
+                        scheds[w].pending() for w in range(self.n)
+                        if al[w]):
+                    break
+                if not al.any():
+                    break  # nobody alive: parked requests are unrecoverable
+                parked, unrouted = unrouted, []
+                self._route(scheds, al, parked, unrouted)
+                if not decode_round(al, R + drain) and not unrouted:
+                    break
+                drain += 1
+            dargs["drain_rounds"] = drain
         # the bank is frozen once gossip stops, so the drain tail of the
         # consensus trace is one value repeated — computed, not assumed
         if drain:

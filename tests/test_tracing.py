@@ -4,24 +4,30 @@
 The contracts under test:
 
   * golden schema — a tracer used the way the fleet/benchmarks use it
-    (spans, explicit-timestamp spans, counters, instants, multiple
-    processes/lanes) emits a trace that ``validate_trace`` accepts, that
-    survives a JSON write/``load_trace`` round-trip, and whose metadata
-    events announce every process/lane exactly once;
+    (nested spans, counters, instants, multiple processes/lanes) emits a
+    trace that ``validate_trace`` accepts, that survives a JSON
+    write/``load_trace`` round-trip, and whose metadata events announce
+    every process/lane exactly once;
   * schema gate actually gates — each malformed-event family raises;
   * metrics semantics — counters are monotonic, histograms expose
     Prometheus cumulative le-buckets, kind collisions are errors;
   * exposition round-trip — ``parse_exposition(reg.exposition())``
-    recovers every sample value, labels and +Inf buckets included.
+    recovers every sample value, labels and +Inf buckets included;
+  * one clock — replay scopes take only their own names, and host spans
+    (``tracing.span``, ``SpanTracer.span``, ``gc_spans``) land in the
+    profiler's host plane, beside the device ops.
 """
+import gc
+import glob
 import json
 import math
 
+import jax
 import numpy as np
 import pytest
 
 from repro.analysis import (MetricsRegistry, SpanTracer, load_trace,
-                            parse_exposition, validate_trace)
+                            parse_exposition, tracing, validate_trace)
 
 
 def _bench_shaped_tracer():
@@ -29,13 +35,15 @@ def _bench_shaped_tracer():
     tr = SpanTracer("bench", metadata={"family": "serve", "seed": 0})
     with tr.span("bench.serve", lane="bench", args={"seed": 0}):
         for r in range(3):
-            t0 = tr.now_us()
-            with tr.span("fleet.decode", process="fleet", lane="decode",
-                         args={"round": r, "active_slots": np.int64(2)}):
-                pass
-            tr.complete("fleet.round", t0, tr.now_us() - t0,
-                        process="fleet", lane="rounds",
-                        args={"round": r, "alive": 4})
+            args = {"round": r}
+            with tr.span("fleet.round", process="fleet", lane="rounds",
+                         args=args):
+                with tr.span("fleet.decode", process="fleet",
+                             lane="decode",
+                             args={"round": r,
+                                   "active_slots": np.int64(2)}):
+                    pass
+                args["alive"] = 4     # filled in before the span closes
             tr.counter("fleet.queue", {"queue_depth": r,
                                        "slot_occupancy": np.float32(0.5)},
                        process="fleet")
@@ -193,3 +201,60 @@ def test_exposition_handles_inf_and_label_escaping():
     [(labels, value)] = parsed["edge_case"].items()
     assert value == math.inf
     assert '\\\\' in labels and '\\"' in labels
+
+
+# -------------------------------------------------- scopes and one clock
+
+def test_scope_takes_only_replay_names():
+    assert len(set(tracing.SCOPES)) == len(tracing.SCOPES)
+    with pytest.raises(ValueError, match="unknown replay scope"):
+        tracing.scope("replay.grads")
+
+    @jax.jit
+    def f(x):
+        with tracing.scope("replay.grad"):
+            return x * 2.0
+    assert 'op_name="jit(f)/replay.grad/mul"' in \
+        f.lower(1.0).compile().as_text()
+
+
+def test_span_args_filled_inside_the_block():
+    tr = SpanTracer("t")
+    args = {"worlds": 2}
+    with tr.span("dispatch.grid", args=args):
+        args["jit_traces"] = 1
+    [ev] = [e for e in tr.events if e["ph"] == "X"]
+    assert ev["args"] == {"worlds": 2, "jit_traces": 1}
+
+
+def test_host_spans_share_the_profiler_clock(tmp_path):
+    """``tracing.span``, ``SpanTracer.span`` and a ``gc`` span opened
+    under ``jax.profiler.trace`` are events of the profile's host plane."""
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer("t")
+    with jax.profiler.trace(str(tmp_path)):
+        with tracing.span("replay.dispatch"):
+            jax.block_until_ready(jax.numpy.ones(4) + 1)
+        with tr.span("fleet.round"):
+            pass
+        with tracing.gc_spans() as stats:
+            gc.collect()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events}
+    assert {"replay.dispatch", "fleet.round", "gc"} <= names
+    assert stats.collections >= 1
+
+
+def test_gc_spans_count_collections_and_unhook():
+    hooks = list(gc.callbacks)
+    with tracing.gc_spans() as stats:
+        gc.collect()
+        gc.collect()
+    assert gc.callbacks == hooks
+    assert stats.collections == 2
+    assert 0 < stats.longest <= stats.seconds
+    gc.collect()
+    assert stats.collections == 2
