@@ -26,6 +26,16 @@ narrowest dtype that embeds every leaf losslessly (f32, else f64).
 Round-tripping is bit-exact for every floating dtype that embeds in
 ``buf_dtype``; anything else is rejected loudly rather than silently
 truncated.
+
+Every ``unpack*`` is one ``split`` of the flat axis, not one slice per
+leaf, because a gradient taken through ``unpack_local`` (a ``grad_fn`` of
+flat replicas) is built by its transpose.  The transpose of a slice pads
+the leaf's cotangent back to full width, so per-leaf slices give a sum of
+one full-width pad per leaf; the transpose of a split is one
+``concatenate``, which writes each element once.  Compiled for a TPU v5e,
+the replay of 16 ResNet-18 workers (56 leaves) spent 127.2 M of its
+388.0 M estimated cycles in two copies of that pad-sum; with the split
+they are one fusion of 8.1 M, and the program 260.8 M (DESIGN.md §17).
 """
 from __future__ import annotations
 
@@ -135,16 +145,22 @@ class FlatLayout:
             cols.append(jnp.zeros((w, self.d - self.d_real), self.buf_dtype))
         return jnp.concatenate(cols, axis=1)
 
+    def _split(self, buf: jax.Array) -> PyTree:
+        """Split the trailing flat axis of ``buf`` into leaves, keeping its
+        leading axes: one ``split`` at the leaf offsets, with ``d_real``
+        as the last cut so the zero tail is a piece of its own, dropped.
+        Its transpose is one ``concatenate`` (see module docstring)."""
+        lead = buf.shape[:-1]
+        cuts = [s.offset for s in self.specs[1:]] + [self.d_real]
+        pieces = jnp.split(buf, cuts, axis=-1)
+        leaves = [p.astype(s.dtype).reshape(lead + s.shape)
+                  for p, s in zip(pieces, self.specs)]
+        return self.treedef.unflatten(leaves)
+
     @scope("replay.unpack")
     def unpack(self, buf: jax.Array) -> PyTree:
         """(W, D) buffer -> stacked pytree with original shapes/dtypes."""
-        w = buf.shape[0]
-        leaves = [
-            buf[:, s.offset:s.offset + s.size]
-            .astype(s.dtype).reshape((w,) + s.shape)
-            for s in self.specs
-        ]
-        return self.treedef.unflatten(leaves)
+        return self._split(buf)
 
     @scope("replay.pack")
     def pack_local(self, tree: PyTree) -> jax.Array:
@@ -159,11 +175,7 @@ class FlatLayout:
     @scope("replay.unpack")
     def unpack_local(self, vec: jax.Array) -> PyTree:
         """(D,) vector -> replica pytree with original shapes/dtypes."""
-        leaves = [
-            vec[s.offset:s.offset + s.size].astype(s.dtype).reshape(s.shape)
-            for s in self.specs
-        ]
-        return self.treedef.unflatten(leaves)
+        return self._split(vec)
 
     @scope("replay.pack")
     def pack_worlds(self, tree: PyTree) -> jax.Array:
@@ -180,13 +192,7 @@ class FlatLayout:
     @scope("replay.unpack")
     def unpack_worlds(self, buf: jax.Array) -> PyTree:
         """(B, W, D) buffer -> world-batched pytree."""
-        b, w = buf.shape[:2]
-        leaves = [
-            buf[:, :, s.offset:s.offset + s.size]
-            .astype(s.dtype).reshape((b, w) + s.shape)
-            for s in self.specs
-        ]
-        return self.treedef.unflatten(leaves)
+        return self._split(buf)
 
 
 # ---------------------------------------------------------------------------

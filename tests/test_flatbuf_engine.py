@@ -31,20 +31,69 @@ def _mixed_dtype_tree(w=None):
 
 # ------------------------------------------------------------------- layout
 
-@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("stacked", [False, True, "worlds"])
 def test_pack_unpack_roundtrip_exact_mixed_dtypes(stacked):
-    tree = _mixed_dtype_tree(w=4 if stacked else None)
-    layout = FlatLayout.from_pytree(tree, stacked=stacked)
+    if stacked == "worlds":  # (B, W, *shape): two worlds of four workers
+        tree = jax.tree.map(lambda a: jnp.stack([a, -a]),
+                            _mixed_dtype_tree(w=4))
+        layout = FlatLayout.from_pytree(tree, worlds=True)
+        buf = layout.pack_worlds(tree)
+        out = layout.unpack_worlds(buf)
+    else:
+        tree = _mixed_dtype_tree(w=4 if stacked else None)
+        layout = FlatLayout.from_pytree(tree, stacked=stacked)
+        buf = layout.pack(tree) if stacked else layout.pack_local(tree)
+        out = layout.unpack(buf) if stacked else layout.unpack_local(buf)
     assert layout.d % 128 == 0 and layout.d >= layout.d_real
-    buf = layout.pack(tree) if stacked else layout.pack_local(tree)
-    out = layout.unpack(buf) if stacked else layout.unpack_local(buf)
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
     # padding columns are zero (reductions over the buffer need no masking)
-    flat = buf if buf.ndim == 1 else buf[0]
+    flat = buf.reshape(-1, layout.d)[0]
     np.testing.assert_array_equal(flat[layout.d_real:], 0.0)
+
+
+def _odd_tree():
+    """Thirty leaves of odd sizes, every third one bf16 (f32 buffer)."""
+    key = jax.random.PRNGKey(1)
+    return {f"l{i:02d}": jax.random.normal(
+        jax.random.fold_in(key, i), (2 * i + 1, 3) if i % 2 else (i + 2,)
+    ).astype(jnp.bfloat16 if i % 3 == 0 else jnp.float32) for i in range(30)}
+
+
+def _tailless_tree():
+    """Leaves that fill the flat width exactly: no padding tail."""
+    return {"w": jnp.linspace(-1.0, 1.0, 200).reshape(8, 25),
+            "b": jnp.arange(56, dtype=jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("make_tree", [_mixed_dtype_tree, _odd_tree,
+                                       _tailless_tree],
+                         ids=["mixed", "odd", "tailless"])
+def test_unpack_local_gradient_is_one_concatenate(make_tree):
+    """The transpose of ``unpack_local`` writes the flat gradient once, one
+    operand per element: no per-leaf pad summed to full width."""
+    tree = make_tree()
+    layout = FlatLayout.from_pytree(tree)
+    assert layout.buf_dtype == jnp.float32
+
+    def loss(t):
+        return sum(jnp.sum(jnp.sin(leaf.astype(jnp.float32)) * (k + 1.5))
+                   for k, leaf in enumerate(jax.tree.leaves(t)))
+
+    def flat_loss(v):
+        return loss(layout.unpack_local(v))
+
+    vec = layout.pack_local(tree)
+    text = str(jax.make_jaxpr(jax.grad(flat_loss))(vec))
+    assert text.count("pad[") == 0
+    assert text.count("concatenate[") == 1
+
+    got = np.asarray(jax.jit(jax.grad(flat_loss))(vec))
+    want = np.asarray(layout.pack_local(jax.jit(jax.grad(loss))(tree)))
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    np.testing.assert_array_equal(got[layout.d_real:], 0.0)
 
 
 def test_layout_rejects_lossy_dtypes():

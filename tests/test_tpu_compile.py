@@ -10,14 +10,20 @@ use — so these tests guard the chip path without a chip.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers import every
 test file.
+
+A last case compiles one gradient tick of a small flat model, vmapped
+over 16 workers, and reads the optimised HLO: the flat gradient must not
+come back as a sum of full-width pads, one per leaf.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import FlatLayout, mix_flat
 from repro.kernels.a2cid2_mixing import kernel as K
 
 W, D, B = 4, 128_404_224, 2
@@ -91,3 +97,43 @@ def test_gossip_kernel_compiles_for_v5e(one_chip, name):
     bank = args[0].size * 4
     assert mem.alias_size_in_bytes >= 2 * bank
     assert mem.temp_size_in_bytes < bank // 64
+
+
+def _odd_model():
+    """Thirty odd-sized f32 leaves: d_real 1,635, D 1,664."""
+    return {f"l{i:02d}": jax.ShapeDtypeStruct(
+        (2 * i + 1, 3) if i % 2 else (i + 2,), jnp.float32)
+        for i in range(30)}
+
+
+def _computations(hlo):
+    """{name: body} of every computation in an HLO module's text."""
+    return dict(re.findall(r"^(?:ENTRY )?%?([\w.-]+) [^\n]*\{\n(.*?)^\}",
+                           hlo, re.M | re.S))
+
+
+def test_flat_gradient_tick_has_no_pad_sum(one_chip):
+    n_workers = 16
+    layout = FlatLayout.from_pytree(_odd_model())
+    bank = (n_workers, layout.d)
+
+    def loss(vec, data):
+        leaves = jax.tree.leaves(layout.unpack_local(vec))
+        return sum(jnp.sum(jnp.tanh(leaf * data[k]))
+                   for k, leaf in enumerate(leaves))
+
+    def tick(bx, bxt, data, dt):
+        g = jax.vmap(jax.grad(loss))(bx, data)
+        return mix_flat(bx - 0.05 * g, bxt - 0.05 * g, 0.7, dt)
+
+    compiled = jax.jit(tick, donate_argnums=(0, 1)).lower(
+        _spec(one_chip, bank), _spec(one_chip, bank),
+        _spec(one_chip, (n_workers, 30)),
+        _spec(one_chip, (n_workers,))).compile()
+    full_pad = re.compile(
+        rf"= f32\[{n_workers},{layout.d}\]\S* pad\(")
+    bodies = _computations(compiled.as_text())
+    assert any(n.startswith("fused") for n in bodies)
+    pads = {name: len(full_pad.findall(body))
+            for name, body in bodies.items()}
+    assert max(pads.values()) <= 2, {n: c for n, c in pads.items() if c}
