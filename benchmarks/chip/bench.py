@@ -25,7 +25,10 @@ telemetry.  The run:
      flight behind the one the host waits on, the window closing on
      ``block_until_ready``; with ``--trace 1`` a shorter stretch is traced
      instead and reduced to the per-layer metrics, every one of which the
-     cell lists has to be found in the trace;
+     cell lists has to be found in the trace.  The scope maps that put
+     each device op down to its replay and model scope (``scopes.maps``)
+     are read from the compiled replay's HLO text after the window has
+     closed, so neither the set-up nor the window pays for them;
   8. replays the first three dispatches with the plain reference
      (``reference.py``) and compares, then prints the result as the last
      line of stdout, and the compared numbers beside their limits as the
@@ -54,6 +57,7 @@ import numpy as np  # noqa: E402
 
 import catalog  # noqa: E402
 import reference  # noqa: E402
+import scopes  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic as T  # noqa: E402
 from peaks import peaks  # noqa: E402
@@ -113,7 +117,7 @@ class EngineStream:
 
     def __init__(self, sim, steps: int, backend: str):
         self.sim, self.steps, self.backend = sim, steps, backend
-        self.fn = self.compiled = None
+        self.fn = self.compiled = self.hlo = None
 
     def dispatches(self, state, sched: dict) -> list[dict]:
         """The schedule's stream, cut into whole dispatches; a tail shorter
@@ -162,9 +166,9 @@ class EngineStream:
         """Compile the scan for one dispatch's shape; on the Pallas
         backend its program has to hold the gossip kernel."""
         self.compiled = self.fn.lower(self.sim, state, placed).compile()
+        self.hlo = self.compiled.as_text()
         if self.backend == "pallas" and not any(
-                GOSSIP_KERNEL in line for line in
-                self.compiled.as_text().splitlines()
+                GOSSIP_KERNEL in line for line in self.hlo.splitlines()
                 if 'custom_call_target="tpu_custom_call"' in line):
             raise RuntimeError(f"the compiled replay holds no "
                                f"tpu_custom_call named {GOSSIP_KERNEL}")
@@ -176,7 +180,8 @@ class EngineStream:
 class Cell:
     """The program built for one cell: the simulator, the weight draw and
     the reading of per-leaf change norms, shared by every seed run in the
-    process."""
+    process.  The program draws each worker's batch through ``batch``, the
+    seam a test breaks without touching the reference's own draw."""
 
     def __init__(self, name: str, cfg: dict, traffic: dict, mod,
                  backend: str = "auto"):
@@ -187,7 +192,7 @@ class Cell:
         self.name, self.cfg, self.traffic, self.mod = name, cfg, traffic, mod
         self.workers = traffic["workers"]
         self.steps = traffic["steps_per_dispatch"]
-        self.prog = mod.program(cfg, traffic)
+        self.prog = mod.program(cfg, traffic, self.batch)
         self.consts = T.a2cid2_constants(traffic)
         self.backend = resolve_backend(backend)
         self.sim = Simulator(self.prog.grad_fn,
@@ -210,6 +215,10 @@ class Cell:
                                      axis=1)) for o, s in slices], axis=1)
             return jnp.stack([bank(bx), bank(bxt)])
         self.norms = norms
+
+    def batch(self, key: jax.Array) -> dict:
+        """One worker's batch for the program, from its key."""
+        return self.mod.example_batch(key, self.cfg, self.traffic)
 
     # ----------------------------------------------------------- one seed
     def start(self, seed: int) -> dict:
@@ -381,14 +390,17 @@ def run_cell(name: str, bench: dict, seed: int, seconds: float,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": int(peak)}
     if trace:
-        red = trace_reduce.reduce(record)
+        red = trace_reduce.reduce(record, scopes.maps(cell.stream.hlo))
         pk = peaks(dev.device_kind)
         facts = {"chips": len(devices), "traced_units": units,
                  "flops_per_unit": mod.flops_per_unit(cfg, traffic),
                  "peak_flops": pk["flops_bf16"],
                  "peak_hbm": pk["hbm_bytes_per_s"],
                  "workers_per_chip": cell.workers // len(devices),
-                 "parameters": cfg["parameters"]}
+                 "parameters": cfg["parameters"],
+                 "grad_ticks": win["grad_ticks"],
+                 "exchanges": win["exchanges"],
+                 "cfg": cfg, "traffic": traffic}
         metrics, missing = {}, []
         for m in catalog.cell_metrics(name, bench, "per_layer"):
             v = catalog.metric_reader(m["name"]).read(red, facts)

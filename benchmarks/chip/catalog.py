@@ -5,11 +5,22 @@ files of their own, so a later change adds a cell, a configuration, a
 traffic mix or a per-layer metric by adding files:
 
   configs/<config>.json   the sizes as run (the entry's ``file``)
-  configs/<config>.py     the program's build of it, its data, its work
-                          counts and its plain reference
+  configs/<config>.py     the program's build of it (``program(cfg,
+                          traffic, batch)``, training on the harness's
+                          ``batch(key)``), its data, its work counts, its
+                          unit of work (``UNIT``: ``train_<UNIT>_per_s``
+                          is its rate), the sizes the CPU tests run it at
+                          (``TEST_SIZE``) and its plain reference
   traffic/<traffic>.json  the schedule, batch and dispatch parameters
   limits/<cell>.json      the limits of the numbers ``correct`` compares
-  metrics/<metric>.py     the reader of one per-layer metric
+  metrics/<metric>.py     the reader of one per-layer metric: ``read(r,
+                          facts)`` over the reduced trace (device time per
+                          op, per replay scope and per ``model.<name>``
+                          scope, ``trace_reduce.reduce``) and the run's
+                          facts, None where it finds nothing to read
+
+and its entries in ``BENCHMARK.json``; a new cell is appended to the
+``workloads`` of the metrics it reports.
 """
 from __future__ import annotations
 
@@ -30,8 +41,8 @@ def load_module(path: pathlib.Path, name: str):
     return mod
 
 
-def benchmark(root: pathlib.Path = ROOT) -> dict:
-    return json.loads((root / "BENCHMARK.json").read_text())
+def benchmark() -> dict:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
 def workload(name: str, bench: dict) -> dict:

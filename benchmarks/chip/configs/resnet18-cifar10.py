@@ -21,6 +21,11 @@ import numpy as np
 
 UNIT = "images"
 
+# the sizes the CPU tests run this configuration at: widths cut so that a
+# whole run takes seconds; the blocks (pre-activation, projection where
+# the width changes) stay the configuration's
+TEST_SIZE = dict(stage_sizes=[1, 1], width=8, norm_groups=4, image_size=8)
+
 
 def units_per_example(cfg: dict, traffic: dict) -> int:
     """Images one worker trains on in one gradient tick."""
@@ -100,9 +105,10 @@ def init_params(key: jax.Array, shapes) -> dict:
     return jax.tree_util.tree_unflatten(jax.tree.structure(shapes), out)
 
 
-def program(cfg: dict, traffic: dict):
+def program(cfg: dict, traffic: dict, batch):
     """The system under test: shapes of one replica, ``pack`` to the flat
-    vector, and the per-worker ``grad_fn`` the simulator vmaps."""
+    vector, and the per-worker ``grad_fn`` the simulator vmaps, which
+    trains on ``batch(key)``, the harness's draw of ``example_batch``."""
     from repro.core.flatbuf import FlatLayout
     from repro.models.resnet import ResNetConfig, init_resnet, resnet_loss
 
@@ -113,7 +119,7 @@ def program(cfg: dict, traffic: dict):
     layout = FlatLayout.from_pytree(shapes)
 
     def grad_fn(vec, key, wid):
-        data = example_batch(jax.random.fold_in(key, wid), cfg, traffic)
+        data = batch(jax.random.fold_in(key, wid))
 
         def loss_fn(v):
             loss, _ = resnet_loss(layout.unpack_local(v), rcfg, data)

@@ -14,18 +14,14 @@ import pytest  # noqa: E402
 
 import catalog  # noqa: E402
 
-# widths cut so that a whole run takes seconds on a CPU; the blocks
-# (pre-activation, projection where the width changes) stay the config's
-TINY = {
-    "resnet18-cifar10": dict(stage_sizes=[1, 1], width=8, norm_groups=4,
-                             image_size=8),
-}
-
 
 def tiny(cell: str) -> tuple[dict, dict]:
-    """(config, traffic) of ``cell`` at test size."""
+    """(config, traffic) of ``cell`` at test size: the configuration at
+    the ``TEST_SIZE`` its module gives, the traffic at a few rounds of
+    small batches."""
     entry = catalog.workload(cell, catalog.benchmark())
-    cfg = dict(catalog.config(entry["config"]), **TINY[entry["config"]])
+    mod = catalog.config_module(entry["config"])
+    cfg = dict(catalog.config(entry["config"]), **mod.TEST_SIZE)
     traffic = dict(catalog.traffic(entry["traffic"]), rounds=16, batch=2)
     traffic["steps_per_dispatch"] = min(traffic["steps_per_dispatch"], 8)
     return cfg, traffic

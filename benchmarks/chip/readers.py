@@ -1,6 +1,14 @@
 """Arithmetic the per-layer metric readers share.  Each takes the reduced
 trace (``trace_reduce.reduce``) and the run's facts, and returns a share in
-percent, or None where the trace holds nothing to read."""
+percent, or None where the trace holds nothing to read.
+
+The facts (``bench.run_cell``): ``chips``; ``traced_units``, the units of
+work the traced window completed, ``grad_ticks`` and ``exchanges``, its
+gradient ticks and pairwise exchanges; ``flops_per_unit`` and
+``parameters`` of the configuration; ``workers_per_chip``; the device's
+``peak_flops`` and ``peak_hbm``; and the configuration ``cfg`` and
+``traffic`` as run, for a reader that counts its kernel's FLOPs or bytes
+with the configuration's own functions."""
 from __future__ import annotations
 
 

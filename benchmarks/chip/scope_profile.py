@@ -10,9 +10,9 @@ one under the profiler.  It prints one JSON line per seed with
 
   * the rate of each window, so the cost of tracing shows;
   * the traced window's busy and idle time (``trace_reduce.reduce``);
-  * device seconds per scope (``scopes.scope_seconds``) and the three
-    scope readings: ``grad_mfu``, ``bank_ms_per_tick``,
-    ``record_ms_per_tick``;
+  * device seconds per replay scope and per model scope
+    (``trace_reduce.reduce`` with ``scopes.maps``) and the three scope
+    readings: ``grad_mfu``, ``bank_ms_per_tick``, ``record_ms_per_tick``;
   * the top device ops, each named with its scope and followed by the
     scopes fused into it;
   * Python's garbage collections in each window (count, seconds, longest)
@@ -54,14 +54,14 @@ def profile(name: str, seeds, seconds: float, devices, *, cfg=None,
     mod = catalog.config_module(entry["config"])
     cell = bench.Cell(name, cfg, traffic, mod, backend)
     pk = peaks(devices[0].device_kind)
-    smap = fused = None
+    maps = fused = None
     for seed in seeds:
         run = cell.start(seed)
         cell.first_steps(run)
         jax.block_until_ready(run["state"])
-        if smap is None:
-            text = cell.stream.compiled.as_text()
-            smap, fused = scopes.scope_map(text), scopes.fused_scopes(text)
+        if maps is None:
+            text = cell.stream.hlo
+            maps, fused = scopes.maps(text), scopes.fused_scopes(text)
         with gc_spans() as gc_plain:
             plain = cell.window(run, seconds)
         trace_dir = tempfile.mkdtemp(prefix="scope-trace-")
@@ -74,8 +74,8 @@ def profile(name: str, seeds, seconds: float, devices, *, cfg=None,
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         run = None
-        red = trace_reduce.reduce(record)
-        scope_s = scopes.scope_seconds(record, smap)
+        red = trace_reduce.reduce(record, maps)
+        scope_s = red["scope_s"]
         facts = {"chips": len(devices), "grad_ticks": traced["grad_ticks"],
                  "traced_units": traced["grad_ticks"] * cell.units,
                  "flops_per_unit": mod.flops_per_unit(cfg, traffic),
@@ -89,11 +89,12 @@ def profile(name: str, seeds, seconds: float, devices, *, cfg=None,
             "grad_ticks": traced["grad_ticks"],
             "busy_s": red["busy_s"], "window_s": red["window_s"],
             "scope_s": dict(sorted(scope_s.items(), key=lambda kv: -kv[1])),
+            "model_s": red["model_s"],
             "grad_mfu": scopes.grad_mfu(scope_s, facts),
             "bank_ms_per_tick": scopes.bank_ms_per_tick(scope_s, facts),
             "record_ms_per_tick": scopes.record_ms_per_tick(scope_s, facts),
             "device_ops": scopes.scoped_ops(red["breakdown"]["device_ops"],
-                                            smap, fused),
+                                            maps.replay, fused),
             "idle_gaps": red["breakdown"]["idle_gaps"],
             "gc": {"untraced": _gc(gc_plain, plain),
                    "traced": _gc(gc_traced, traced)},

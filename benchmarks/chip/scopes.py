@@ -12,10 +12,20 @@ op_name.  ``replay.grad`` splits into ``replay.grad.fwd`` and
 ``replay.grad.bwd`` by whether the name stack past the token holds
 ``transpose(``, which is how JAX names the backward pass.  An instruction
 with no token is ``unscoped``.
+
+A model marks its own parts with ``jax.named_scope("model.<name>")``; no
+list of them is kept here.  An instruction's model scope is the innermost
+(last) ``model.<name>`` token of its op_name, with ``.bwd`` where the name
+that holds it has ``transpose(`` (JAX writes a differentiated scope as
+``jvp(model.<name>)`` and its backward as ``transpose(jvp(model.<name>))``)
+and ``.fwd`` otherwise.  Model scopes are read apart from the replay's:
+a model token inside ``replay.grad`` leaves that instruction in
+``replay.grad.fwd`` or ``.bwd``.
 """
 from __future__ import annotations
 
 import re
+from typing import NamedTuple
 
 # the program's scope names; a test holds this copy equal to the program's
 SCOPES = ("replay.unpack", "replay.grad", "replay.pack", "replay.update",
@@ -28,6 +38,7 @@ _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+) .*\{\s*$")
 _TOKEN = re.compile(r"(?<![\w.])(" + "|".join(re.escape(s) for s in SCOPES)
                     + r")(?![\w.])")
+_MODEL = re.compile(r"(?<![\w.\-])model(?:\.[\w\-]+)+")
 
 
 def scope_of(op_name: str) -> str:
@@ -44,20 +55,58 @@ def scope_of(op_name: str) -> str:
     return "replay.grad.bwd" if "transpose(" in rest else "replay.grad.fwd"
 
 
+def model_scope_of(op_name: str) -> str | None:
+    """The model scope of one instruction, ``model.<name>.fwd`` or
+    ``.bwd``, or None where its op_name holds no ``model.`` token."""
+    last = None
+    for m in _MODEL.finditer(op_name):
+        last = m
+    if last is None:
+        return None
+    start = op_name.rfind(";", 0, last.start()) + 1
+    name = op_name[start:].split(";")[0]
+    return last.group(0) + (".bwd" if "transpose(" in name else ".fwd")
+
+
 def leaf(name: str) -> str:
     """An op's name as the map keys it: the profiler's ``%`` dropped."""
     return name.lstrip("%")
 
 
-def scope_map(hlo_text: str) -> dict[str, str]:
-    """Instruction name -> scope, for every instruction of the module."""
-    out = {}
+def _op_names(hlo_text: str):
+    """(instruction name, its op_name or "") of every instruction."""
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
         if m:
             op = _OP_NAME.search(m.group(2))
-            out[m.group(1)] = scope_of(op.group(1)) if op else UNSCOPED
+            yield m.group(1), op.group(1) if op else ""
+
+
+def scope_map(hlo_text: str) -> dict[str, str]:
+    """Instruction name -> scope, for every instruction of the module."""
+    return {instr: scope_of(op) for instr, op in _op_names(hlo_text)}
+
+
+def model_map(hlo_text: str) -> dict[str, str]:
+    """Instruction name -> model scope, for the instructions that have
+    one."""
+    out = {}
+    for instr, op in _op_names(hlo_text):
+        scope = model_scope_of(op)
+        if scope is not None:
+            out[instr] = scope
     return out
+
+
+class ScopeMaps(NamedTuple):
+    """Both maps of one compiled program, as ``trace_reduce.reduce`` takes
+    them."""
+    replay: dict[str, str]
+    model: dict[str, str]
+
+
+def maps(hlo_text: str) -> ScopeMaps:
+    return ScopeMaps(scope_map(hlo_text), model_map(hlo_text))
 
 
 def fused_scopes(hlo_text: str) -> dict[str, set[str]]:

@@ -4,7 +4,9 @@ Each test drives a whole run of a cell at test size on the CPU (past the
 harness's look for a chip) with the timed path broken underneath, and sees
 ``correct`` come out false; a sound run comes out true, and the control --
 the plain reference computed in bfloat16, put in the program's place --
-fails the cell's limits.
+fails the cell's limits.  Every lookup goes through ``catalog`` when the
+test runs, so a cell that a configuration adds by files alone is driven
+the same way.
 """
 from __future__ import annotations
 
@@ -16,16 +18,14 @@ import bench
 import catalog
 import reference
 from repro.core import engine
-from repro.models import resnet
 
-BENCH = catalog.benchmark()
-CELLS = [w["name"] for w in BENCH["workloads"]]
+CELLS = [w["name"] for w in catalog.benchmark()["workloads"]]
 
 
 def run(cell, tiny_cell, seed=9):
     cfg, traffic = tiny_cell(cell)
-    return bench.run_cell(cell, BENCH, seed, 0.2, False, jax.devices(),
-                          cfg=cfg, traffic=traffic,
+    return bench.run_cell(cell, catalog.benchmark(), seed, 0.2, False,
+                          jax.devices(), cfg=cfg, traffic=traffic,
                           limits=catalog.limits(cell), backend="ref")
 
 
@@ -49,11 +49,13 @@ def test_state_left_unchanged_is_caught(cell, tiny_cell, monkeypatch):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_half_batch_is_caught(cell, tiny_cell, monkeypatch):
-    def halve(batch):
-        return {k: v[: v.shape[0] // 2] for k, v in batch.items()}
-    loss = resnet.resnet_loss
-    monkeypatch.setattr(resnet, "resnet_loss", lambda p, c, b:
-                        loss(p, c, halve(b)))
+    """The program trains on the first half of each batch it is handed;
+    the reference draws its own, whole."""
+    draw = bench.Cell.batch
+
+    def halved(self, key):
+        return {k: v[: v.shape[0] // 2] for k, v in draw(self, key).items()}
+    monkeypatch.setattr(bench.Cell, "batch", halved)
     assert not run(cell, tiny_cell)["correct"]
 
 
@@ -71,7 +73,7 @@ def test_altered_gossip_answer_is_caught(cell, tiny_cell, monkeypatch):
 @pytest.mark.parametrize("cell", CELLS)
 def test_bf16_control_fails_the_limits(cell, tiny_cell):
     cfg, traffic = tiny_cell(cell)
-    entry = catalog.workload(cell, BENCH)
+    entry = catalog.workload(cell, catalog.benchmark())
     c = bench.Cell(cell, cfg, traffic,
                    catalog.config_module(entry["config"]), "ref")
     r = c.start(12)

@@ -67,11 +67,55 @@ def test_fused_scopes_on_fixed_hlo():
     assert f == {"fusion.7": {"replay.update", "replay.mix"}}
 
 
+# the model marks its parts inside replay.grad, as JAX names them under
+# vmap and differentiation
+MODEL_HLO = HLO.replace(
+    "vmap(jvp())/dot_general",
+    "vmap(jvp(model.block))/model.attn/dot_general").replace(
+    "vmap(transpose(jvp()))/dot_general",
+    "vmap(transpose(jvp(model.block)))/dot_general")
+
+
+def test_model_scopes_on_fixed_hlo():
+    """The innermost ``model.`` token names the model scope, ``transpose(``
+    in its name makes it the backward pass, and the replay scopes stay as
+    they were without the tokens."""
+    assert MODEL_HLO != HLO
+    m = scopes.maps(MODEL_HLO)
+    assert m.replay == scopes.scope_map(HLO)
+    assert m.model == {"dot.3": "model.attn.fwd", "dot.4": "model.block.bwd"}
+    assert scopes.model_map(HLO) == {}
+    assert scopes.model_scope_of("a/mymodel.x/y") is None
+    assert scopes.model_scope_of(
+        "a/transpose(jvp(model.mlp))/x;b/jvp(model.head)/y") \
+        == "model.head.fwd"
+    assert scopes.model_scope_of(
+        "a/jvp(model.mlp)/x;b/transpose(jvp(model.mlp-2.up))/y") \
+        == "model.mlp-2.up.bwd"
+
+
+def test_model_seconds_leave_replay_seconds_as_they_were():
+    rec = _record([["%dot.3", 0, 3 * MS, "op"],
+                   ["%dot.4", 3 * MS, 5 * MS, "op"],
+                   ["%fusion.7", 8 * MS, 1 * MS, "op"]])
+    r = trace_reduce.reduce(rec, scopes.maps(MODEL_HLO))
+    assert r["model_s"] == {"model.attn.fwd": pytest.approx(0.003),
+                            "model.block.bwd": pytest.approx(0.005)}
+    plain = trace_reduce.reduce(rec, scopes.maps(HLO))
+    assert plain["model_s"] == {}
+    assert r["scope_s"] == plain["scope_s"] == scopes.scope_seconds(
+        rec, scopes.scope_map(HLO))
+    assert r["scope_s"]["replay.grad.fwd"] == pytest.approx(0.003)
+    assert r["scope_s"]["replay.grad.bwd"] == pytest.approx(0.005)
+
+
 def _grad_fn(p, key, wid):
     x = jax.random.normal(key, (4, 8))
 
     def loss(p):
-        return jnp.mean((jnp.tanh(x @ p["w1"]) @ p["w2"]) ** 2)
+        with jax.named_scope("model.mlp"):
+            h = jnp.tanh(x @ p["w1"])
+        return jnp.mean((h @ p["w2"]) ** 2)
     return jax.value_and_grad(loss)(p)
 
 
@@ -97,14 +141,21 @@ def _compiled_replay(body: str) -> str:
 @pytest.mark.parametrize("body", ["plain", "channel", "worlds"])
 def test_replay_bodies_carry_every_scope(body):
     """Every scope reaches the compiled replay's op_name metadata, and the
-    model's matmuls fall under the forward or the backward pass."""
+    model's matmuls fall under the forward or the backward pass; the
+    model's own scope splits the same way, and leaves the replay's map as
+    it is."""
     text = _compiled_replay(body)
     for name in tracing.SCOPES:
         assert f"/{name}/" in text, name
-    m = scopes.scope_map(text)
-    dots = {m[line.split(" = ")[0].split()[-1].lstrip("%")]
-            for line in text.splitlines() if " dot(" in line}
-    assert dots == {"replay.grad.fwd", "replay.grad.bwd"}
+    m = scopes.maps(text)
+    instrs = [line.split(" = ")[0].split()[-1].lstrip("%")
+              for line in text.splitlines() if " dot(" in line]
+    assert {m.replay[i] for i in instrs} == {"replay.grad.fwd",
+                                             "replay.grad.bwd"}
+    assert {m.model[i] for i in instrs if i in m.model} == {
+        "model.mlp.fwd", "model.mlp.bwd"}
+    assert all(m.replay[i] == "replay.grad." + s.rsplit(".", 1)[1]
+               for i, s in m.model.items())
 
 
 def _record(*ops_per_chip):

@@ -11,6 +11,7 @@ import pytest
 
 import catalog
 import readers
+import scopes
 import trace_reduce
 from peaks import peaks
 
@@ -142,39 +143,132 @@ def test_recorded_chip_trace():
     assert not any(n.startswith(("%while", "%conditional")) for n in names)
 
 
-def traced_run(monkeypatch, tiny_cell, ops):
-    """A whole --trace 1 run of the cell at test size on the CPU, its trace
-    replaced by one chip's ``ops`` over the traced window, read against
-    the v5e's peaks."""
+def _recorded():
+    path = catalog.HERE / "testdata" / "trace_nano_lm_ring4.json.gz"
+    with gzip.open(path, "rt") as f:
+        return json.load(f)
+
+
+def test_recorded_chip_trace_reduces_as_before():
+    """Without scope maps ``reduce`` gives every key it gave before op
+    times were added, with the same values to the last digit
+    (``trace_nano_lm_ring4.reduced.json`` holds them), and so the same
+    readings; it adds the time and calls of every leaf op."""
+    rec = _recorded()
+    before = json.loads((catalog.HERE / "testdata" /
+                         "trace_nano_lm_ring4.reduced.json").read_text())
+    r = trace_reduce.reduce(rec)
+    assert set(r) == set(before) | {"op_s", "op_calls"}
+    assert json.loads(json.dumps({k: r[k] for k in before})) == before
+    facts = {"workers_per_chip": 4, "parameters": 128_404_224,
+             "peak_hbm": 819e9, "traced_units": 8 * 256 * 4 * 3,
+             "flops_per_unit": 6 * 128_404_224, "chips": 1,
+             "peak_flops": 197e12}
+    for read in (readers.idle_share, readers.mfu, readers.gossip_roofline):
+        assert read(r, facts) == read(before, facts)
+    # every leaf op in the window, not only the top ten
+    (_, lo, dur), = [s for s in rec["host"] if s[0] == "window"]
+    leaves = trace_reduce._leaves([o for o in rec["devices"][0]["ops"]
+                                   if o[1] + o[2] > lo and o[1] < lo + dur])
+    assert len(r["op_s"]) > 10 and set(r["op_s"]) == {o[0] for o in leaves}
+    assert sum(r["op_s"].values()) == pytest.approx(
+        sum(o[2] for o in leaves) * 1e-9, rel=1e-12)
+    assert sum(r["op_calls"].values()) == len(leaves)
+    assert r["op_calls"]["%a2cid2_gossip.2"] == r["gossip_calls"] == 3
+    top = sorted(r["op_s"].items(), key=lambda kv: -kv[1])[:10]
+    assert r["breakdown"]["device_ops"] == [list(kv) for kv in top]
+
+
+def test_op_time_and_calls_averaged_over_chips():
+    r = trace_reduce.reduce(
+        record([["%fusion.1", 0, 4 * MS, "op"],
+                ["%fusion.1", 10 * MS, 6 * MS, "op"],
+                ["%copy.2", 20 * MS, 1 * MS, "op"]],
+               [["%fusion.1", 0, 2 * MS, "op"]]))
+    assert r["op_s"] == {"%fusion.1": pytest.approx(0.006),
+                         "%copy.2": pytest.approx(0.0005)}
+    assert r["op_calls"] == {"%fusion.1": 1.5, "%copy.2": 0.5}
+    assert "scope_s" not in r and "model_s" not in r
+
+
+def scoped_ops(maps) -> list:
+    """One op named by an instruction of each replay scope and each model
+    scope of the compiled replay, 2 ms each, back to back, then one 30 ms
+    gossip kernel call: every scope reader finds something to read."""
+    picked = {}
+    for m in (maps.replay, maps.model):
+        for instr, scope in sorted(m.items()):
+            picked.setdefault(scope, instr)
+    ops = [[f"%{instr}", i * 2 * MS, 2 * MS, "op"]
+           for i, instr in enumerate(picked.values())]
+    return ops + [["%a2cid2_gossip.2", len(ops) * 2 * MS, 30 * MS,
+                   "gossip"]]
+
+
+def traced_run(monkeypatch, tiny_cell, ops, cell=None):
+    """A whole --trace 1 run of the cell (the first, by default) at test
+    size on the CPU, its trace replaced by one chip's ``ops`` over the
+    traced window, read against the v5e's peaks.  ``ops`` may be a
+    function of the compiled replay's scope maps."""
     import contextlib
 
     import bench
 
-    cell = catalog.benchmark()["workloads"][0]["name"]
+    cell = cell or catalog.benchmark()["workloads"][0]["name"]
     cfg, traffic = tiny_cell(cell)
+    compiled = {}
+    compile_ = bench.EngineStream.compile
+
+    def compile(self, state, placed):
+        compile_(self, state, placed)
+        compiled["maps"] = scopes.maps(self.hlo)
+
+    def extract(trace_dir):
+        return record(ops(compiled["maps"]) if callable(ops) else ops)
+    monkeypatch.setattr(bench.EngineStream, "compile", compile)
     monkeypatch.setattr(jax.profiler, "trace",
                         lambda d: contextlib.nullcontext())
-    monkeypatch.setattr(trace_reduce, "extract", lambda d: record(ops))
+    monkeypatch.setattr(trace_reduce, "extract", extract)
     monkeypatch.setattr(bench, "peaks", lambda kind: peaks("TPU v5 lite"))
     return bench.run_cell(cell, catalog.benchmark(), 5, 0.2, True,
                           jax.devices(), cfg=cfg, traffic=traffic,
                           backend="ref")
 
 
-def test_traced_run_reports_every_listed_metric(monkeypatch, tiny_cell):
-    out = traced_run(monkeypatch, tiny_cell, [
-        ["%fusion.1", 0, 60 * MS, "op"],
-        ["%a2cid2_gossip.2", 60 * MS, 30 * MS, "gossip"]])
-    listed = {m["name"] for m in catalog.benchmark()["per_layer"]}
+def check_traced_run(cell, monkeypatch, tiny_cell):
+    """A traced run of ``cell`` reports exactly the per-layer metrics the
+    cell lists, each read from the trace."""
+    traced = []
+
+    def ops(maps):
+        traced[:] = scoped_ops(maps)
+        return traced
+    out = traced_run(monkeypatch, tiny_cell, ops, cell)
+    listed = {m["name"] for m in catalog.cell_metrics(
+        cell, catalog.benchmark(), "per_layer")}
     assert set(out["metrics"]) == listed
-    assert out["device"]["busy_s"] == pytest.approx(0.09)
-    assert out["breakdown"]["device_ops"][0] == ["%fusion.1",
-                                                 pytest.approx(0.06)]
+    assert all(m["value"] > 0 for m in out["metrics"].values()), \
+        out["metrics"]
+    assert out["device"]["busy_s"] == pytest.approx(
+        sum(o[2] for o in traced) * 1e-9)
+    assert out["breakdown"]["device_ops"][0] == ["%a2cid2_gossip.2",
+                                                 pytest.approx(0.03)]
+    return out
+
+
+def test_traced_run_reports_every_listed_metric(monkeypatch, tiny_cell):
+    """Each cell against the per-layer metrics it lists, the replay's
+    scope readings among them."""
+    for cell in [w["name"] for w in catalog.benchmark()["workloads"]]:
+        out = check_traced_run(cell, monkeypatch, tiny_cell)
+        if cell == "resnet18-cifar10.ring16.b64":
+            assert {"grad_mfu.resnet", "bank_ms_per_tick.resnet",
+                    "record_ms_per_tick.resnet"} <= set(out["metrics"])
 
 
 def test_traced_run_without_gossip_ops_fails(monkeypatch, tiny_cell):
     """A cell that lists a gossip roofline and whose trace shows no gossip
     kernel (renamed, or routed around) fails, rather than dropping it."""
-    with pytest.raises(RuntimeError, match="gossip_roofline"):
+    with pytest.raises(RuntimeError, match=r"\['gossip_roofline"):
         traced_run(monkeypatch, tiny_cell,
-                   [["%fusion.1", 0, 60 * MS, "op"]])
+                   lambda maps: scoped_ops(maps)[:-1])

@@ -7,15 +7,19 @@ small record: the harness's host spans (``window``, ``schedule``,
 " = "), where kind is ``gossip`` for the A2CiD2 gossip kernels and ``op``
 for anything else.  Host spans and device operations share the profiler's
 clock.  ``reduce`` turns that record into busy and idle time and kernel
-time over the traced window.  A loop is busy time, but its op time and
-kernel calls are those of the ops in its body: only ops that hold no other
-op are counted one by one.
+time over the traced window, and device time and calls of every op by
+name; given the compiled program's scope maps (``scopes.maps``), device
+time per replay scope and per model scope too.  A loop is busy time, but
+its op time and kernel calls are those of the ops in its body: only ops
+that hold no other op are counted one by one.
 """
 from __future__ import annotations
 
 import collections
 import glob
 import re
+
+from scopes import UNSCOPED, scope_seconds
 
 HOST_SPANS = ("window", "schedule", "dispatch", "wait")
 GOSSIP = re.compile(r"a2cid2_")
@@ -99,9 +103,15 @@ def _length(intervals) -> float:
     return float(sum(e - s for s, e in intervals))
 
 
-def reduce(record: dict) -> dict:
+def reduce(record: dict, scopes=None) -> dict:
     """Window, busy and kernel times, in seconds, per chip and averaged,
-    plus the breakdown of device time and idle gaps."""
+    plus the breakdown of device time and idle gaps.
+
+    ``op_s`` and ``op_calls`` hold the device seconds and calls of every
+    op name, averaged over chips as ``gossip_s`` is.  With ``scopes``
+    (``scopes.ScopeMaps``), ``scope_s`` holds the device seconds of each
+    replay scope (``scopes.scope_seconds``) and ``model_s`` those of each
+    model scope the program marks."""
     windows = [s for s in record["host"] if s[0] == "window"]
     if not windows or not record["devices"]:
         raise ValueError("the trace holds no window span or no device ops")
@@ -111,6 +121,7 @@ def reduce(record: dict) -> dict:
                                                    "schedule")]
     per_chip = []
     op_time = collections.Counter()
+    op_calls = collections.Counter()
     gaps = []
     for dev in record["devices"]:
         live = [o for o in dev["ops"] if o[1] + o[2] > lo and o[1] < hi]
@@ -124,6 +135,7 @@ def reduce(record: dict) -> dict:
         })
         for o in ops:
             op_time[o[0]] += o[2] * 1e-9 / len(record["devices"])
+            op_calls[o[0]] += 1
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         for s, e in zip(edges[0::2], edges[1::2]):
             if e > s:
@@ -138,4 +150,11 @@ def reduce(record: dict) -> dict:
     out["breakdown"] = {
         "device_ops": [[k, v] for k, v in op_time.most_common(10)],
         "idle_gaps": sorted(gaps, key=lambda g: -g[1])[:10]}
+    out["op_s"] = dict(op_time)
+    out["op_calls"] = {k: v / n for k, v in op_calls.items()}
+    if scopes is not None:
+        out["scope_s"] = scope_seconds(record, scopes.replay)
+        out["model_s"] = {k: v for k, v in
+                          scope_seconds(record, scopes.model).items()
+                          if k != UNSCOPED}
     return out
