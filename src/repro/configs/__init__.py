@@ -19,6 +19,7 @@ ARCHITECTURES = (
     "glm4-9b",
     "yi-34b",
     "qwen3-0.6b",
+    "kanana-2-30b-a3b",
 )
 
 EXTRA = ("nano-lm", "paper-resnet18")  # paper repro + example-scale configs

@@ -255,10 +255,15 @@ def init_mla(key, cfg: ModelConfig, dtype):
     D, H = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 8)
+    if m.q_lora_rank:
+        q = {"w_dq": dense_init(ks[0], D, (D, m.q_lora_rank), dtype),
+             "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
+             "w_uq": dense_init(ks[1], m.q_lora_rank,
+                                (m.q_lora_rank, H * qk), dtype)}
+    else:
+        q = {"w_q": dense_init(ks[0], D, (D, H * qk), dtype)}
     return {
-        "w_dq": dense_init(ks[0], D, (D, m.q_lora_rank), dtype),
-        "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
-        "w_uq": dense_init(ks[1], m.q_lora_rank, (m.q_lora_rank, H * qk), dtype),
+        **q,
         "w_dkv": dense_init(ks[2], D,
                             (D, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": init_rmsnorm(m.kv_lora_rank, dtype),
@@ -277,8 +282,11 @@ def _mla_qkv(p, cfg: ModelConfig, x, positions):
     B, S, _ = x.shape
     H = cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    cq = rmsnorm(x @ p["w_dq"], p["q_norm"], cfg.norm_eps)
-    q = (cq @ p["w_uq"]).reshape(B, S, H, qk)
+    if m.q_lora_rank:
+        q = rmsnorm(x @ p["w_dq"], p["q_norm"], cfg.norm_eps) @ p["w_uq"]
+    else:
+        q = x @ p["w_q"]
+    q = q.reshape(B, S, H, qk)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)

@@ -33,13 +33,29 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance auxiliary loss
     dense_d_ff: int = 0          # parallel dense residual MLP (Arctic) hidden
+    # routing: "softmax" (top-k of softmax probabilities, auxiliary
+    # balancing loss) or "sigmoid" (DeepSeek-V3 noaux_tc: top-k of
+    # sigmoid affinities plus a fixed per-expert bias, no auxiliary loss)
+    scoring: str = "softmax"
+    routed_scale: float = 1.0    # routed weights times this (DeepSeek-V3)
+    # dropless: every (token, choice) routed to a held expert is computed,
+    # by a grouped matmul over the held experts; else capacity dispatch
+    dropless: bool = False
+    # (first, count) range of expert ids this layer holds (expert
+    # parallelism: it routes over all num_experts and computes its own
+    # experts' part of the result); None holds all
+    held: tuple[int, int] | None = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held or (0, self.num_experts)
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
     """Multi-head Latent Attention (DeepSeek-V3)."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536      # 0 => no query LoRA: q = x W_q
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -160,6 +176,10 @@ class ModelConfig:
                 assert self.rglru is not None
             if b.mlp in ("moe", "moe+dense"):
                 assert self.moe is not None
+                first, count = self.moe.held_range
+                assert 0 <= first and count > 0
+                assert first + count <= self.moe.num_experts
+                assert self.moe.dropless or self.moe.held is None
 
 
 def uniform_blocks(block: Block, n: int) -> tuple[tuple[tuple[Block, ...], int], ...]:

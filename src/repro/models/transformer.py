@@ -50,25 +50,33 @@ def init_block(key, cfg: ModelConfig, block: Block) -> dict:
 
 def apply_block(p, cfg: ModelConfig, block: Block, x, positions
                 ) -> tuple[jax.Array, jax.Array]:
-    """Residual block; returns (x, aux_loss)."""
+    """Residual block; returns (x, aux_loss).  Its parts are marked for
+    the tracer as ``model.<mixer>``, ``model.mlp`` (a dense MLP) or the
+    MoE's ``model.router``/``.experts``/``.shared``, each norm in the
+    scope of the part it feeds."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    if block.mixer == "attn":
-        h = attention.apply_attention(p["mixer"], cfg, h, positions,
-                                      block.window)
-    elif block.mixer == "mla":
-        h = attention.apply_mla(p["mixer"], cfg, h, positions, block.window)
-    elif block.mixer == "ssd":
-        h = ssm.apply_ssd(p["mixer"], cfg, h)
-    elif block.mixer == "rglru":
-        h = rglru.apply_rglru(p["mixer"], cfg, h)
+    with jax.named_scope(f"model.{block.mixer}"):
+        h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+        if block.mixer == "attn":
+            h = attention.apply_attention(p["mixer"], cfg, h, positions,
+                                          block.window)
+        elif block.mixer == "mla":
+            h = attention.apply_mla(p["mixer"], cfg, h, positions,
+                                    block.window)
+        elif block.mixer == "ssd":
+            h = ssm.apply_ssd(p["mixer"], cfg, h)
+        elif block.mixer == "rglru":
+            h = rglru.apply_rglru(p["mixer"], cfg, h)
     x = x + h
-    if block.mlp != "none":
-        h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if block.mlp == "dense":
-            h = apply_mlp(p["mlp"], h, cfg.mlp_act)
-        else:
-            h, aux = apply_moe(p["mlp"], h, cfg.moe, cfg.mlp_act)
+    if block.mlp == "dense":
+        with jax.named_scope("model.mlp"):
+            h = apply_mlp(p["mlp"], rmsnorm(x, p["norm2"], cfg.norm_eps),
+                          cfg.mlp_act)
+        x = x + h
+    elif block.mlp != "none":
+        with jax.named_scope("model.router"):
+            h = rmsnorm(x, p["norm2"], cfg.norm_eps)
+        h, aux = apply_moe(p["mlp"], h, cfg.moe, cfg.mlp_act)
         x = x + h
     return x, aux
 
@@ -160,7 +168,8 @@ class Model:
 
         Returns (logits, aux_loss, final_hidden)."""
         cfg = self.cfg
-        x = embed_inputs(params["embed"], cfg, inputs)
+        with jax.named_scope("model.embed"):
+            x = embed_inputs(params["embed"], cfg, inputs)
         B, S = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         aux_total = jnp.zeros((), jnp.float32)
@@ -188,9 +197,20 @@ class Model:
                     unit_fn, policy=jax.checkpoint_policies.nothing_saveable)
             x, auxs = jax.lax.scan(lambda c, p_: unit_fn(c, p_), x, group_p)
             aux_total = aux_total + jnp.sum(auxs)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = apply_lm_head(params["head"], params["embed"], cfg, x)
+        with jax.named_scope("model.head"):
+            x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            logits = apply_lm_head(params["head"], params["embed"], cfg, x)
         return logits, aux_total, x
+
+    def _log_probs(self, logits: jax.Array) -> jax.Array:
+        """log_softmax over the vocabulary, in f32: the padding columns
+        past ``vocab_size`` (ids no token has) take no probability."""
+        cfg = self.cfg
+        logits = logits.astype(jnp.float32)
+        if cfg.padded_vocab != cfg.vocab_size:
+            real = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+            logits = jnp.where(real, logits, attention.NEG_INF)
+        return jax.nn.log_softmax(logits, axis=-1)
 
     # ------------------------------------------------------------------ loss
     def loss(self, params: dict, batch: dict, *, remat: bool = False
@@ -204,9 +224,10 @@ class Model:
         logits = logits.reshape(B, S, C, cfg.padded_vocab)
         if labels.ndim == 2:
             labels = labels[..., None]
-        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ce = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0]
-        loss = jnp.mean(ce)
+        with jax.named_scope("model.head"):
+            lp = self._log_probs(logits)
+            ce = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0]
+            loss = jnp.mean(ce)
         metrics = {"ce": loss, "aux": aux}
         if cfg.mtp and cfg.input_mode == "tokens":
             mtp_loss = self._mtp_loss(params, batch, h)
@@ -236,7 +257,7 @@ class Model:
             labels = labels[..., None]
         # labels are already inputs shifted by 1 => use labels shifted by 1
         tgt = labels[:, 1:]
-        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        lp = self._log_probs(logits)
         ce = -jnp.take_along_axis(lp, tgt[..., None], axis=-1)[..., 0]
         return jnp.mean(ce)
 

@@ -137,3 +137,30 @@ def test_flat_gradient_tick_has_no_pad_sum(one_chip):
     pads = {name: len(full_pad.findall(body))
             for name, body in bodies.items()}
     assert max(pads.values()) <= 2, {n: c for n, c in pads.items() if c}
+
+
+def test_dropless_moe_gradient_compiles_for_v5e(one_chip):
+    """The gradient of a dropless expert layer at Kanana-2-30B-A3B's widths
+    (d 2,048, 8 held of 128 routed-over experts of width 768, top 6, two
+    shared experts as one SwiGLU of 1,536), vmapped over two workers as
+    the simulator does: the TPU's ragged dot takes no batch dimension, so
+    the grouped matmuls must reach the compiler one worker at a time."""
+    from repro.models.config import MoEConfig
+    from repro.models.layers import apply_moe, init_moe
+
+    moe = MoEConfig(num_experts=128, top_k=6, d_expert=768,
+                    shared_expert=True, d_shared=1536, scoring="sigmoid",
+                    routed_scale=2.448, dropless=True, held=(0, 8))
+    params = jax.eval_shape(lambda k: init_moe(k, 2048, moe, jnp.float32),
+                            jax.random.PRNGKey(0))
+
+    def grad(p, x):
+        return jax.grad(lambda p: jnp.sum(apply_moe(p, x, moe)[0]))(p)
+
+    def spec(leaf):
+        return _spec(one_chip, (2,) + leaf.shape, leaf.dtype)
+    compiled = jax.jit(jax.vmap(grad)).lower(
+        jax.tree.map(spec, params),
+        _spec(one_chip, (2, 1, 512, 2048))).compile()
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo or "ragged_dot" in hlo
